@@ -87,7 +87,7 @@ def _spmm_micro_rows(rng, smoke: bool) -> List[Row]:
 
     for frac in (1.0, 0.5, 0.1):
         active = jnp.asarray((rng.random(n_rb) < frac).astype(np.int32))
-        dt = _time_us(lambda: spmm(ell, x, active, interpret=True),
+        dt = _time_us(lambda: spmm(ell, x, active),
                       iters=2 if smoke else 3)
         tiles_total = int(ell.valid.sum())
         tiles_live = int(ell.valid[np.asarray(active) != 0].sum())
@@ -148,11 +148,11 @@ def _nap_step_rows(rng, smoke: bool) -> List[Row]:
 
     def two_launch_impl(x):
         return two_launch_step(tiles, tile_col, valid, active, x, c_inf,
-                               s_inf, nact, t_s, interpret=True)
+                               s_inf, nact, t_s)
 
     def fused_impl(x):
         return fused_step(tiles, tile_col, valid, active, x, c_inf,
-                          s_inf, nact, t_s, interpret=True)
+                          s_inf, nact, t_s)
 
     impls = {"segment": jax.jit(segment_impl),
              "two_launch": jax.jit(two_launch_impl),
@@ -222,8 +222,7 @@ def _support_rows(rng, smoke: bool) -> List[Row]:
         t0 = time.perf_counter()
         x = spmm_block_ell(jnp.asarray(packed.tiles),
                            jnp.asarray(packed.tile_col),
-                           jnp.asarray(packed.valid), active, x,
-                           interpret=True)
+                           jnp.asarray(packed.valid), active, x)
         x.block_until_ready()
         dt = time.perf_counter() - t0
         live = int(packed.valid[np.asarray(step_act[l - 1]) != 0].sum())

@@ -587,7 +587,7 @@ def _series_structural(g, cfg, nai, stream) -> Dict:
         jnp.asarray(packed.x_inf), packed.n_batch, spmm_impl="block_ell",
         ell=(jnp.asarray(packed.tiles), jnp.asarray(packed.tile_col),
              jnp.asarray(packed.valid)),
-        step_active=jnp.asarray(sa), interpret=True)
+        step_active=jnp.asarray(sa))
     return {
         "series_rows": int(series.shape[1]),
         "nb_pad": int(packed.n_batch),

@@ -60,14 +60,13 @@ for l in range(1, T_MAX + 1):
     needed[:nb] |= active_batch          # batch rows live while active
     needed[:nb] &= active_batch | (sup.hop[:nb] <= remaining)
     live = active_blocks_from_nodes(jnp.asarray(needed), ell.n_pad)
-    x = spmm(ell, x, live, interpret=True)
+    x = spmm(ell, x, live)
     tiles_possible += int(ell.valid.sum())
     tiles_touched += int(ell.valid[np.asarray(live) != 0].sum())
     if l < T_MIN or l == T_MAX:
         continue
     d, exits, _ = exit_decision(x[:nb], x_inf[:nb],
-                                jnp.asarray(active_batch), T_S,
-                                interpret=True)
+                                jnp.asarray(active_batch), T_S)
     newly = np.asarray(exits) & (exit_order == 0)
     exit_order[newly] = l
     active_batch &= ~np.asarray(exits)

@@ -73,8 +73,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from jax.experimental.shard_map import shard_map
-
 from repro.kernels.nap_step import nap_step_fused
 from repro.kernels.spmm import spmm_block_ell
 from repro.kernels.spmm.kernel import CB, RB
@@ -190,7 +188,7 @@ class PropagationBackend:
         shape checks only)."""
 
     def step(self, ops: dict, x_full, node_active, active_rb, ts2, *,
-             n_batch: int, n_rows: int, interpret: bool
+             n_batch: int, n_rows: int
              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """One propagation + exit-decision step.
 
@@ -220,7 +218,7 @@ class SegmentBackend(PropagationBackend):
     }
 
     def step(self, ops, x_full, node_active, active_rb, ts2, *,
-             n_batch, n_rows, interpret):
+             n_batch, n_rows):
         contrib = ops["coef"][:, None] * x_full[ops["src"]]
         out = jax.ops.segment_sum(contrib, ops["dst"], num_segments=n_rows)
         return out, _distance_exits(out, ops["x_inf"], ts2, n_batch)
@@ -241,9 +239,9 @@ class BlockEllBackend(PropagationBackend):
     }
 
     def step(self, ops, x_full, node_active, active_rb, ts2, *,
-             n_batch, n_rows, interpret):
+             n_batch, n_rows):
         out = spmm_block_ell(ops["tiles"], ops["tile_col"], ops["valid"],
-                             active_rb, x_full, interpret=interpret)
+                             active_rb, x_full)
         return out, _distance_exits(out, ops["x_inf"], ts2, n_batch)
 
 
@@ -283,13 +281,12 @@ class FusedBackend(PropagationBackend):
                              f"{c.shape} {s.shape}")
 
     def step(self, ops, x_full, node_active, active_rb, ts2, *,
-             n_batch, n_rows, interpret):
+             n_batch, n_rows):
         c_inf = ops["c_inf"].reshape(-1, 1).astype(x_full.dtype)
         s_inf = ops["s_inf"].reshape(1, -1).astype(x_full.dtype)
         out, exits, _blk_still = nap_step_fused(
             ops["tiles"], ops["tile_col"], ops["valid"], active_rb, x_full,
-            c_inf, s_inf, node_active[:, None], ts2.reshape(1),
-            interpret=interpret)
+            c_inf, s_inf, node_active[:, None], ts2.reshape(1))
         # any(blk_still) == any(node_active & ~exits): the generic loop
         # recovers the live flag from exit_order, so blk_still is not
         # threaded out (it exists for two_launch parity of the raw kernel)
@@ -325,8 +322,7 @@ def pack_operands(backend: PropagationBackend, packed,
 
 
 # ------------------------------------------------------------ the loop
-def _masked_loop(backend, nai, ops, x0, n_batch, n_rows, interpret,
-                 gather, any_fn):
+def _masked_loop(backend, nai, ops, x0, n_batch, n_rows, gather, any_fn):
     """The ONE masked NAP fori-loop (previously triplicated per impl).
 
     Carries ``(x (n_rows, f), series (T_max+1, n_batch, f), exit_order
@@ -357,8 +353,7 @@ def _masked_loop(backend, nai, ops, x0, n_batch, n_rows, interpret,
                         jnp.float32(-1.0))
         active_rb = sa[l - 1] * live if sa is not None else None
         x, exits = backend.step(ops, gather(x), node_active, active_rb,
-                                ts2, n_batch=n_batch, n_rows=n_rows,
-                                interpret=interpret)
+                                ts2, n_batch=n_batch, n_rows=n_rows)
         exit_order = jnp.where((node_active != 0) & exits, l, exit_order)
         live = any_fn(exit_order == 0)
         # cache-hit rows: overwrite whatever the (edge-dropped) step left
@@ -420,8 +415,7 @@ def _halo_gather(gather_mode: str, halo: dict, rows_loc: int):
 
 
 def make_superstep(backend: PropagationBackend, nai, *, n_batch: int,
-                   n_rows: int, interpret: bool = True, mesh=None,
-                   gather_mode: str = "dense"):
+                   n_rows: int, mesh=None, gather_mode: str = "dense"):
     """One NAP propagation step as its own jitted callable — the unit
     of work of the offline full-graph driver
     (`repro.launch.full_graph_infer`), which checkpoints state between
@@ -461,8 +455,7 @@ def make_superstep(backend: PropagationBackend, nai, *, n_batch: int,
         sa = ops.get("step_active")
         active_rb = sa[l - 1] * live if sa is not None else None
         x, exits = backend.step(ops, gather(x), node_active, active_rb,
-                                ts2, n_batch=nb, n_rows=rows,
-                                interpret=interpret)
+                                ts2, n_batch=nb, n_rows=rows)
         exit_order = jnp.where((node_active != 0) & exits, l, exit_order)
         return x, exit_order
 
@@ -512,10 +505,10 @@ def make_superstep(backend: PropagationBackend, nai, *, n_batch: int,
                         ).astype(jnp.int32),
                     nb=nb_loc, rows=rows_loc)
 
-    # check_rep=False for the same reason as run_propagation: parity
-    # tests, not the rep tracker, are the correctness oracle
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    # check_vma=False for the same reason as run_propagation: parity
+    # tests, not the replication checker, are the correctness oracle
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
 
     @jax.jit
     def step_sharded(operands, x, exit_order, l):
@@ -529,8 +522,8 @@ def make_superstep(backend: PropagationBackend, nai, *, n_batch: int,
 
 
 def run_propagation(backend: PropagationBackend, nai, operands: dict,
-                    x0, n_batch: int, *, interpret: bool = True,
-                    mesh=None, gather_mode: str = "dense",
+                    x0, n_batch: int, *, mesh=None,
+                    gather_mode: str = "dense",
                     classify=None, cls_params=None,
                     return_series: bool = False):
     """Run the masked NAP loop for any registered backend.
@@ -571,7 +564,7 @@ def run_propagation(backend: PropagationBackend, nai, operands: dict,
         backend.validate(operands, x0, n_batch)
         exit_order, series = _masked_loop(
             backend, nai, dict(operands), x0, n_batch, x0.shape[0],
-            interpret, gather=lambda x: x,
+            gather=lambda x: x,
             any_fn=lambda m: jnp.any(m).astype(jnp.int32))
         if classify is None:
             return exit_order, series
@@ -630,8 +623,7 @@ def run_propagation(backend: PropagationBackend, nai, operands: dict,
                        seed_vals=ops["seed_vals"][0])
         backend.validate(ops, x0_loc, nb_loc)
         exit_order, series = _masked_loop(
-            backend, nai, ops, x0_loc, nb_loc, rows_loc, interpret,
-            gather=gather,
+            backend, nai, ops, x0_loc, nb_loc, rows_loc, gather=gather,
             any_fn=lambda m: (jax.lax.psum(jnp.any(m).astype(jnp.int32),
                                            "data") > 0).astype(jnp.int32))
         if classify is None:
@@ -641,10 +633,10 @@ def run_propagation(backend: PropagationBackend, nai, operands: dict,
             return exit_order, preds, series
         return exit_order, preds
 
-    # check_rep=False: the rep-tracker cannot see through the fori_loop
-    # carry; correctness is covered by the bit-parity tests
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    # check_vma=False: the replication checker cannot see through the
+    # fori_loop carry; correctness is covered by the bit-parity tests
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     if classify is not None:
         return fn(*arrays, x0, cls_params)
     return fn(*arrays, x0)

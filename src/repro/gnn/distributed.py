@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.gnn.backends import get_backend, pack_operands, run_propagation
 from repro.gnn.packing import (pack_support, shard_batch_perm,
@@ -105,8 +104,7 @@ def pack_graph(g, n_shards: int, r: float = 0.5,
 
 
 def distributed_series(mesh, g, k: int, r: float = 0.5,
-                       spmm_impl: str = "segment", *,
-                       interpret: bool = True, nb_bucket=None,
+                       spmm_impl: str = "segment", *, nb_bucket=None,
                        s_bucket=None, tb_bucket=None,
                        gather_mode: str = "dense"):
     """[X^(0..k)] computed with the sharded backend step; host-verifiable
@@ -131,10 +129,13 @@ def distributed_series(mesh, g, k: int, r: float = 0.5,
     if be.uses_dense_x_inf:
         ops["x_inf"] = jnp.asarray(packed.x_inf)
     _, series = run_propagation(be, nai, ops, jnp.asarray(packed.x0),
-                                packed.n_batch, interpret=interpret,
+                                packed.n_batch,
                                 mesh=mesh if D > 1 else None,
                                 gather_mode=gather_mode if halo
                                 else "dense")
+    # un-permute on the host: a gather of the row-sharded series by a
+    # host permutation has no sharding JAX can infer
+    series = np.asarray(series)
     if D > 1:
         series = series[:, shard_batch_perm(packed.n_batch, D), :]
     f = g.feat_dim
@@ -149,7 +150,7 @@ def distributed_nap_distances(mesh, x, x_inf):
         d2 = jnp.sum(jnp.square(x - xi), axis=1, keepdims=True)
         return jax.lax.psum(d2, "model")
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P("data", "model"), P("data", "model")),
-                   out_specs=P("data", None))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P("data", "model"), P("data", "model")),
+                       out_specs=P("data", None))
     return jnp.sqrt(fn(x, x_inf)[:, 0])

@@ -17,6 +17,12 @@ import jax.numpy as jnp
 
 from repro.nn.params import ParamDef, init_tree
 
+# f32 matmuls at full f32 precision: the TPU's default rounds f32
+# operands to one bf16 pass, which would break the f32 contract the
+# serving paths are checked against (a bf16 path is a separate, gated
+# change)
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass(frozen=True)
 class GNNConfig:
@@ -72,10 +78,12 @@ def _combine(cfg: GNNConfig, feats: jax.Array, l: int, p) -> jax.Array:
         return jnp.moveaxis(sub, 0, 1).reshape(feats.shape[1], -1)
     if cfg.base_model == "gamlp":
         sub = feats[:l + 1]
-        scores = jnp.einsum("lnf,fa->lna", sub, p["att_w"])
-        scores = jnp.einsum("lna,a->ln", jax.nn.tanh(scores), p["att_v"])
+        scores = jnp.einsum("lnf,fa->lna", sub, p["att_w"],
+                            precision=HIGHEST)
+        scores = jnp.einsum("lna,a->ln", jax.nn.tanh(scores), p["att_v"],
+                            precision=HIGHEST)
         w = jax.nn.softmax(scores, axis=0)                    # (l+1, N)
-        return jnp.einsum("ln,lnf->nf", w, sub)
+        return jnp.einsum("ln,lnf->nf", w, sub, precision=HIGHEST)
     raise ValueError(cfg.base_model)
 
 
@@ -90,7 +98,7 @@ def apply_classifier(cfg: GNNConfig, p, feats, l: int, *,
             key, sub = jax.random.split(key)
             mask = jax.random.bernoulli(sub, 1 - cfg.dropout, x.shape)
             x = jnp.where(mask, x / (1 - cfg.dropout), 0.0)
-        x = x @ p[f"w{i}"] + p[f"b{i}"]
+        x = jnp.matmul(x, p[f"w{i}"], precision=HIGHEST) + p[f"b{i}"]
         if i < n_layers - 1:
             x = jax.nn.relu(x)
     return x

@@ -233,8 +233,7 @@ def order_distribution(result: NAIResult, k: int) -> np.ndarray:
 def infer_batch_masked(cfg: GNNConfig, nai: NAIConfig, params,
                        sup_src, sup_dst, sup_coef, x0, x_inf, n_batch: int,
                        *, spmm_impl: str = "segment", ell=None,
-                       step_active=None, x_inf_factors=None,
-                       interpret: bool = True, mesh=None,
+                       step_active=None, x_inf_factors=None, mesh=None,
                        halo_operands=None, gather_mode: str = "dense"):
     """Compiled NAP: fori over orders with exit masks (static shapes).
 
@@ -289,14 +288,12 @@ def infer_batch_masked(cfg: GNNConfig, nai: NAIConfig, params,
         ops["s_inf"] = jnp.asarray(x_inf_factors[1], x0.dtype)
     if backend.uses_dense_x_inf:
         ops["x_inf"] = x_inf
-    return run_propagation(backend, nai, ops, x0, n_batch,
-                           interpret=interpret, mesh=mesh,
+    return run_propagation(backend, nai, ops, x0, n_batch, mesh=mesh,
                            gather_mode=gather_mode)
 
 
 def make_compiled_infer(cfg: GNNConfig, nai: NAIConfig, *,
                         spmm_impl: str = "block_ell",
-                        interpret: bool = True,
                         donate: Optional[bool] = None,
                         mesh=None, gather_mode: str = "dense",
                         return_series: bool = False):
@@ -373,9 +370,9 @@ def make_compiled_infer(cfg: GNNConfig, nai: NAIConfig, *,
         if backend.uses_dense_x_inf:
             ops["x_inf"] = x_inf
         out = run_propagation(
-            backend, nai, ops, x0, nb, interpret=interpret, mesh=mesh,
-            gather_mode=gather_mode, classify=classify,
-            cls_params=cls_params, return_series=return_series)
+            backend, nai, ops, x0, nb, mesh=mesh, gather_mode=gather_mode,
+            classify=classify, cls_params=cls_params,
+            return_series=return_series)
         if return_series:
             exit_order, preds, series = out
         else:

@@ -8,6 +8,7 @@
                     layers + the long-context serving variant)
 
 Each subpackage: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-wrapper), ref.py (pure-jnp oracle). Validated in interpret=True mode on CPU;
-TPU is the compile target.
+wrapper), ref.py (pure-jnp oracle). Interpret mode follows the platform
+(`repro.runtime.pallas_interpret`): emulated on the CPU, where the tests
+validate against the oracles, compiled on the TPU.
 """

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.runtime import pallas_interpret
+
 BQ = 128
 BK = 128
 NEG_INF = -1.0e30
@@ -75,9 +77,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    interpret: bool = True):
+                    interpret=None):
     """q, k, v: (BH, S, hd) with S % BQ == 0 == S % BK.
-    Returns (BH, S, hd)."""
+    Returns (BH, S, hd). `interpret` defaults to the platform's mode
+    (`repro.runtime.pallas_interpret`)."""
+    if interpret is None:
+        interpret = pallas_interpret()
     BH, S, hd = q.shape
     Sk = k.shape[1]
     assert S % BQ == 0 and Sk % BK == 0, (S, Sk)

@@ -7,7 +7,7 @@ from repro.kernels.flash_attention.kernel import BK, BQ, flash_attention
 
 
 def gqa_flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                        interpret: bool = True):
+                        interpret=None):
     """q (B, S, H, hd); k, v (B, S, KV, hd). Pads S to the block size,
     repeats KV heads to H, runs the kernel, unpads."""
     B, S, H, hd = q.shape

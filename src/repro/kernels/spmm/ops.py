@@ -60,15 +60,14 @@ def pad_features(x: np.ndarray, n_pad: int) -> np.ndarray:
     return out
 
 
-def spmm(ell: BlockEll, x, active=None, *, interpret: bool = True):
+def spmm(ell: BlockEll, x, active=None):
     """One propagation step. x (n_pad, F_pad); active (n_rb,) or None
     (= all active). Returns (n_pad, F_pad)."""
     n_rb = ell.tile_col.shape[0]
     if active is None:
         active = jnp.ones((n_rb,), jnp.int32)
     return spmm_block_ell(jnp.asarray(ell.tiles), jnp.asarray(ell.tile_col),
-                          jnp.asarray(ell.valid), active, x,
-                          interpret=interpret)
+                          jnp.asarray(ell.valid), active, x)
 
 
 def active_blocks_from_nodes(node_active, n_pad: int) -> jnp.ndarray:

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.runtime import pallas_interpret
+
 CHUNK = 16  # matches repro.nn.rwkv.CHUNK (f32-safe decay factorization)
 
 
@@ -57,9 +59,12 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_scr):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def wkv6(r, k, v, logw, u, *, interpret=True):
+def wkv6(r, k, v, logw, u, *, interpret=None):
     """r/k/v/logw: (BH, T, hd) f32, T % CHUNK == 0; u: (BH, hd).
-    Returns out (BH, T, hd) f32 with zero initial state."""
+    Returns out (BH, T, hd) f32 with zero initial state. `interpret`
+    defaults to the platform's mode (`repro.runtime.pallas_interpret`)."""
+    if interpret is None:
+        interpret = pallas_interpret()
     BH, T, hd = r.shape
     assert T % CHUNK == 0, (T, CHUNK)
     grid = (BH, T // CHUNK)

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 from repro.kernels.wkv6.kernel import CHUNK, wkv6
 
 
-def wkv6_heads(r, k, v, logw, u, *, interpret: bool = True):
+def wkv6_heads(r, k, v, logw, u, *, interpret=None):
     """r/k/v/logw (B, T, H, hd) f32; u (H, hd). Pads T to CHUNK; returns
     (B, T, H, hd). Padding steps use logw=0 (no decay), k=0 — state-neutral,
     matching repro.nn.rwkv's masking."""

@@ -103,7 +103,6 @@ class OfflineConfig:
     ckpt_dir: str
     spmm_impl: str = "segment"
     gather_mode: str = "alltoall"    # collapses to dense at D=1
-    interpret: bool = True
     resume: bool = True
     watchdog_s: float = 0.0          # 0 = no per-superstep watchdog
     superstep_retries: int = 2
@@ -290,8 +289,7 @@ def run_full_graph_infer(store, cfg, params, nai, ocfg: OfflineConfig,
 
     # ------------------------------------------------- superstep loop
     step_fn = make_superstep(be, nai, n_batch=nb_pad, n_rows=n_pad,
-                             interpret=ocfg.interpret, mesh=mesh,
-                             gather_mode=gather_mode)
+                             mesh=mesh, gather_mode=gather_mode)
     if mesh is not None:
         logical = operand_logical(be, gather_mode)
         ops_dev = {k: jax.device_put(
@@ -434,6 +432,7 @@ def _main(argv=None) -> int:
     from repro.gnn.nai import NAIConfig
     from repro.gnn.store import MmapStore
     from repro.launch.mesh import make_serving_mesh
+    from repro.runtime import enable_compile_cache
 
     ap = argparse.ArgumentParser(
         description="Offline checkpointed full-graph NAI inference "
@@ -470,6 +469,7 @@ def _main(argv=None) -> int:
                     help="also write the run summary JSON here")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     store = MmapStore(args.store)
     if args.t_s is None:
         q = 0.5 if args.t_s_quantile is None else args.t_s_quantile

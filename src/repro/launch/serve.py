@@ -1,7 +1,9 @@
 """Serving launcher.
 
   * GNN mode (the paper's scenario): batched NAI inference over a stream of
-    unseen-node requests through repro.serving.NAIServingEngine.
+    unseen-node requests through repro.serving.NAIServingEngine, in the
+    compiled mode (sample -> pack on the host, one jitted NAP + classify
+    program on the device).
   * LM mode: batched decode with KV cache for a (reduced) assigned arch,
     optionally with Adaptive-Depth Inference early exits.
 
@@ -19,12 +21,13 @@ import numpy as np
 
 from repro.configs import ARCHS, get_config, smoke
 from repro.models import decoder_lm as M
+from repro.runtime import enable_compile_cache
 
 
 def serve_gnn(args) -> None:
     from repro.gnn import (DistillConfig, GNNConfig, NAIConfig, load_dataset,
                            train_nai)
-    from repro.serving import NAIServingEngine
+    from repro.serving import EngineConfig, NAIServingEngine
     g = load_dataset(args.gnn, scale=args.scale, seed=args.seed)
     cfg = GNNConfig("sgc", g.features.shape[1], g.num_classes, k=args.k,
                     hidden=64, mlp_layers=2, dropout=0.1)
@@ -34,7 +37,10 @@ def serve_gnn(args) -> None:
     params, _ = train_nai(cfg, g, dc)
     nai = NAIConfig(t_s=args.t_s, t_min=1, t_max=args.k // 2 + 1,
                     batch_size=args.batch)
-    engine = NAIServingEngine(cfg, nai, params, g)
+    engine = NAIServingEngine(
+        cfg, nai, params, g,
+        config=EngineConfig(mode="compiled", spmm_impl="segment",
+                            pipeline_depth=2))
 
     rng = np.random.default_rng(args.seed)
     n_req = min(args.requests, len(g.test_idx))
@@ -49,6 +55,9 @@ def serve_gnn(args) -> None:
     print(f"[serve-gnn] p50={s['p50_ms']:.1f}ms p95={s['p95_ms']:.1f}ms "
           f"p99={s['p99_ms']:.1f}ms mean_exit_order={s['mean_exit_order']:.2f}")
     print(f"[serve-gnn] exit histogram: {dict(sorted(stats.exit_hist.items()))}")
+    if s["failed"] or s["retried"]:
+        raise SystemExit(f"[serve-gnn] {s['failed']} failed and "
+                         f"{s['retried']} retried requests")
 
 
 def serve_lm(args) -> None:
@@ -75,8 +84,10 @@ def serve_lm(args) -> None:
         out_tokens.append(np.asarray(tok[:, 0]))
     jax.block_until_ready(tok)
     dt = time.perf_counter() - t0
+    dev = jax.devices()[0]
     print(f"[serve-lm] {cfg.name}: {args.tokens} steps, batch {B}: "
-          f"{1e3 * dt / args.tokens:.1f} ms/step (CPU, correctness run)")
+          f"{1e3 * dt / args.tokens:.1f} ms/step on {dev.platform} "
+          f"({dev.device_kind})")
     print(f"[serve-lm] sample continuation: {np.stack(out_tokens)[:8, 0]}")
 
 
@@ -94,6 +105,7 @@ def main():
     ap.add_argument("--t-s", type=float, default=16.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.gnn:
         serve_gnn(args)
     elif args.arch:

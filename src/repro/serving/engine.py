@@ -133,7 +133,6 @@ class EngineConfig:
     gather_mode: str = "halo"        # sharded frontier exchange
     pipeline_depth: int = 1          # 1 = serial, 2 = one batch in flight
     max_wait_s: float = 0.01         # batch former age bound
-    interpret: bool = True           # Pallas interpret mode (CPU CI)
     donate: Optional[bool] = None    # operand donation (None = backend)
     latency_window: int = 4096       # LatencyRing capacity
     mesh: object = None              # mesh with a "data" axis, or None
@@ -376,8 +375,7 @@ class NAIServingEngine:
                                         spec(*dims, mesh=self.mesh))
                     for name, dims in logical.items()}
             self._runner = make_compiled_infer(
-                cfg, nai, spmm_impl=spmm_impl, interpret=ec.interpret,
-                donate=ec.donate, mesh=self.mesh,
+                cfg, nai, spmm_impl=spmm_impl, donate=ec.donate, mesh=self.mesh,
                 gather_mode=self.gather_mode,
                 return_series=self.cache is not None)
             self._cls_params = {
@@ -424,6 +422,22 @@ class NAIServingEngine:
         store across per-class engines close it once per engine."""
         self.flush()
         self.store.close()
+
+    def pooled_bytes(self) -> Dict[str, int]:
+        """Largest host bytes of each pooled pack operand (``tiles``,
+        ``x0``, ``src``, ...) over every buffer set the pool holds — what
+        one batch ships to the device per operand, at its bucket high
+        water mark. Empty in host mode."""
+        sizes: Dict[str, int] = {}
+        for slots in self._pack_pool.values():
+            for p in slots:
+                if p is None:
+                    continue
+                for f in dataclasses.fields(p):
+                    a = getattr(p, f.name)
+                    if isinstance(a, np.ndarray):
+                        sizes[f.name] = max(sizes.get(f.name, 0), a.nbytes)
+        return sizes
 
     @property
     def donate_argnums(self) -> tuple:

@@ -72,8 +72,7 @@ def test_bit_identical_to_serving_compiled_path(setup):
     be, packed = pack_graph(store, 1, cfg.r, "segment", stationary=True)
     ops = {k: jnp.asarray(v)
            for k, v in pack_operands(be, packed).items()}
-    run = make_compiled_infer(cfg, nai, spmm_impl="segment",
-                              interpret=True)
+    run = make_compiled_infer(cfg, nai, spmm_impl="segment")
     preds, eo = run(params["cls"], ops, jnp.asarray(packed.x0),
                     jnp.asarray(packed.x_inf))
     np.testing.assert_array_equal(ref.predictions,

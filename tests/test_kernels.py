@@ -1,5 +1,5 @@
-"""Pallas kernel validation vs the pure-jnp oracles (interpret=True): shape
-and dtype sweeps per kernel (deliverable c)."""
+"""Pallas kernel validation vs the pure-jnp oracles (interpret mode, which
+the CPU platform selects): shape and dtype sweeps per kernel."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -34,7 +34,7 @@ def test_spmm_shapes(rng, n, deg, f):
     ell = build_block_ell(src, dst, coef, n)
     x = rng.standard_normal((n, f)).astype(np.float32)
     xp = jnp.asarray(pad_features(x, ell.n_pad))
-    out = spmm(ell, xp, interpret=True)
+    out = spmm(ell, xp)
     ref = ref_spmm_dense(src, dst, coef, ell.n_pad, xp,
                          np.ones(ell.tile_col.shape[0], np.int32))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -49,7 +49,7 @@ def test_spmm_nap_predication(rng, frac_active):
     active = (rng.random(n_rb) < frac_active).astype(np.int32)
     x = rng.standard_normal((192, 64)).astype(np.float32)
     xp = jnp.asarray(pad_features(x, ell.n_pad))
-    out = spmm(ell, xp, jnp.asarray(active), interpret=True)
+    out = spmm(ell, xp, jnp.asarray(active))
     ref = ref_spmm_tiles(ell.tiles, ell.tile_col, ell.valid, active, xp)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
@@ -63,7 +63,7 @@ def test_spmm_dtype_bf16(rng):
     ell = build_block_ell(src, dst, coef, 128)
     x = rng.standard_normal((128, 128)).astype(np.float32)
     xp = jnp.asarray(pad_features(x, ell.n_pad)).astype(jnp.bfloat16)
-    out = spmm(ell, xp, interpret=True)
+    out = spmm(ell, xp)
     ref = ref_spmm_dense(src, dst, coef, ell.n_pad, xp.astype(jnp.float32),
                          np.ones(ell.tile_col.shape[0], np.int32))
     assert out.dtype == jnp.bfloat16
@@ -85,7 +85,7 @@ def test_nap_exit_shapes(rng, n, f):
     xi = jnp.asarray(rng.standard_normal((n, f)), jnp.float32)
     act = jnp.asarray(rng.random(n) < 0.7)
     t_s = float(np.sqrt(f) * 1.2)
-    d, e, blk = exit_decision(x, xi, act, t_s, interpret=True)
+    d, e, blk = exit_decision(x, xi, act, t_s)
     ref_d = jnp.linalg.norm(x - xi, axis=1)
     np.testing.assert_allclose(np.asarray(d), np.asarray(ref_d), rtol=1e-4)
     ref_e = np.asarray(act) & (np.asarray(ref_d) < t_s)
@@ -101,7 +101,7 @@ def test_nap_exit_vs_oracle_padded(rng):
     xi = jnp.zeros((n_pad, f_pad)).at[:n, :f].set(
         jnp.asarray(rng.standard_normal((n, f)), jnp.float32))
     ap = jnp.zeros((n_pad, 1), jnp.int32).at[:n, 0].set(1)
-    for out_k, out_r in zip(nap_exit(x, xi, ap, 15.0, interpret=True),
+    for out_k, out_r in zip(nap_exit(x, xi, ap, 15.0),
                             ref_nap_exit(x, xi, ap, 15.0)):
         np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                    rtol=1e-4, atol=1e-4)
@@ -115,8 +115,7 @@ def test_flash_attention_sweep(rng, S, hd, causal, window):
     q = jnp.asarray(rng.standard_normal((2, S, hd)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((2, S, hd)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((2, S, hd)), jnp.float32)
-    out = flash_attention(q, k, v, causal=causal, window=window,
-                          interpret=True)
+    out = flash_attention(q, k, v, causal=causal, window=window)
     ref = ref_attention(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
@@ -126,7 +125,7 @@ def test_flash_attention_bf16(rng):
     q = jnp.asarray(rng.standard_normal((1, 128, 64)), jnp.bfloat16)
     k = jnp.asarray(rng.standard_normal((1, 128, 64)), jnp.bfloat16)
     v = jnp.asarray(rng.standard_normal((1, 128, 64)), jnp.bfloat16)
-    out = flash_attention(q, k, v, interpret=True)
+    out = flash_attention(q, k, v)
     ref = ref_attention(q.astype(jnp.float32), k.astype(jnp.float32),
                         v.astype(jnp.float32))
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -137,7 +136,7 @@ def test_gqa_wrapper_unpadded_seq(rng):
     q = jnp.asarray(rng.standard_normal((2, 100, 8, 32)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((2, 100, 2, 32)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((2, 100, 2, 32)), jnp.float32)
-    out = gqa_flash_attention(q, k, v, interpret=True)
+    out = gqa_flash_attention(q, k, v)
     kr = jnp.repeat(k, 4, 2)
     vr = jnp.repeat(v, 4, 2)
     qf = q.transpose(0, 2, 1, 3).reshape(16, 100, 32)
@@ -161,7 +160,7 @@ def test_wkv6_kernel_vs_sequential(rng, T, hd, H):
     ).astype(np.float32)
     u = (rng.standard_normal((H, hd)) * 0.1).astype(np.float32)
     out = wkv6_heads(jnp.asarray(r), jnp.asarray(k), jnp.asarray(v),
-                     jnp.asarray(logw), jnp.asarray(u), interpret=True)
+                     jnp.asarray(logw), jnp.asarray(u))
     flat = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, T, hd)
     ref = ref_wkv6_sequential(
         flat(r), flat(k), flat(v), flat(logw),
@@ -179,8 +178,8 @@ def test_wkv6_state_continuity_across_chunks(rng):
     v = jnp.asarray(rng.standard_normal((BH, T, hd)), jnp.float32)
     lw = jnp.full((BH, T, hd), -0.1, jnp.float32)
     u = jnp.zeros((BH, hd), jnp.float32)
-    full = wkv6(r, k, v, lw, u, interpret=True)
+    full = wkv6(r, k, v, lw, u)
     # zeroing the first chunk's k must change the second chunk's output
     k2 = k.at[:, :CHUNK].set(0.0)
-    alt = wkv6(r, k2, v, lw, u, interpret=True)
+    alt = wkv6(r, k2, v, lw, u)
     assert float(jnp.abs(full[:, CHUNK:] - alt[:, CHUNK:]).max()) > 1e-3

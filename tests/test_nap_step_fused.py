@@ -61,8 +61,8 @@ def test_fused_matches_two_launch_and_oracle(rng, frac_active, frac_nodes):
     t_s = 9.0
     ops = (jnp.asarray(ell.tiles), jnp.asarray(ell.tile_col),
            jnp.asarray(ell.valid), active, x, c_inf, s_inf, nact, t_s)
-    out_f = fused_step(*ops, interpret=True)
-    out_t = two_launch_step(*ops, interpret=True)
+    out_f = fused_step(*ops)
+    out_t = two_launch_step(*ops)
     out_r = ref_nap_step(*ops[:8], t_s * t_s)
     for f_arr, t_arr, r_arr in zip(out_f, out_t, out_r):
         np.testing.assert_allclose(np.asarray(f_arr), np.asarray(t_arr),
@@ -83,7 +83,7 @@ def test_all_exited_row_block_skip(rng):
     out, exits, blk = fused_step(
         jnp.asarray(ell.tiles), jnp.asarray(ell.tile_col),
         jnp.asarray(ell.valid), jnp.zeros((n_rb,), jnp.int32), x,
-        c_inf, s_inf, jnp.zeros((nb, 1), jnp.int32), 9.0, interpret=True)
+        c_inf, s_inf, jnp.zeros((nb, 1), jnp.int32), 9.0)
     assert float(jnp.abs(out).max()) == 0.0
     assert int(exits.sum()) == 0 and int(blk.sum()) == 0
 
@@ -98,13 +98,44 @@ def test_negative_ts2_gates_exits(rng):
     _, exits, blk = nap_step_fused(
         jnp.asarray(ell.tiles), jnp.asarray(ell.tile_col),
         jnp.asarray(ell.valid), jnp.ones((n_rb,), jnp.int32), x,
-        c_inf, s_inf, nact, jnp.asarray([-1.0], jnp.float32),
-        interpret=True)
+        c_inf, s_inf, nact, jnp.asarray([-1.0], jnp.float32))
     assert int(exits.sum()) == 0
     expect_blk = np.asarray(nact)[:, 0].reshape(-1, RB).any(axis=1)
     assert np.array_equal(np.asarray(blk)[:nb // RB, 0],
                           expect_blk.astype(np.int32))
     assert int(np.asarray(blk)[nb // RB:].sum()) == 0
+
+
+@pytest.mark.parametrize("kernel", ["spmm_block_ell", "nap_step_fused"])
+def test_row_block_chunks_are_bit_identical(rng, monkeypatch, kernel):
+    """Splitting the row blocks into several calls (what bounds the
+    prefetched tables to SMEM on large supports) must not change a bit:
+    a budget of four row blocks per call against a single call."""
+    import jax
+    from repro.kernels.spmm import kernel as spmm_kernel
+    ell, x, c_inf, s_inf = _operands(rng, n=320, nb=40)
+    n_rb, tb = ell.tile_col.shape
+    nb = c_inf.shape[0]
+    active = jnp.asarray((rng.random(n_rb) < 0.7).astype(np.int32)
+                         ).at[:nb // RB].set(1)
+    nact = jnp.asarray((rng.random(nb) < 0.6).astype(np.int32))[:, None]
+    tiles = (jnp.asarray(ell.tiles), jnp.asarray(ell.tile_col),
+             jnp.asarray(ell.valid), active, x)
+    if kernel == "spmm_block_ell":
+        run = functools.partial(spmm_kernel.spmm_block_ell, *tiles)
+    else:
+        run = functools.partial(nap_step_fused, *tiles, c_inf, s_inf, nact,
+                                jnp.asarray([81.0], jnp.float32))
+    whole = jax.tree.map(np.asarray, run())
+    budget = 4 * 4 * (2 * tb + 1)
+    with monkeypatch.context() as m:
+        m.setattr(spmm_kernel, "SMEM_TABLE_BYTES", budget)
+        assert spmm_kernel.row_block_chunk(n_rb, tb) == 4 < n_rb
+        jax.clear_caches()
+        chunked = jax.tree.map(np.asarray, run())
+    jax.clear_caches()
+    for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(chunked)):
+        assert np.array_equal(a, b)
 
 
 # ------------------------------------------------ full NAP loop parity
@@ -167,7 +198,7 @@ def _fused_orders(packed, nai, step_active):
              jnp.asarray(packed.valid)),
         step_active=jnp.asarray(step_active),
         x_inf_factors=(jnp.asarray(packed.c_inf),
-                       jnp.asarray(packed.s_inf)), interpret=True)
+                       jnp.asarray(packed.s_inf)))
     return np.asarray(orders), series
 
 
@@ -210,7 +241,7 @@ def test_fused_infer_matches_block_ell_infer(packed_case):
         jnp.asarray(packed.x_inf), packed.n_batch, spmm_impl="block_ell",
         ell=(jnp.asarray(packed.tiles), jnp.asarray(packed.tile_col),
              jnp.asarray(packed.valid)),
-        step_active=jnp.asarray(sa), interpret=True)
+        step_active=jnp.asarray(sa))
     assert np.array_equal(of, np.asarray(ob))
     np.testing.assert_allclose(np.asarray(series_f), np.asarray(series_b),
                                rtol=1e-4, atol=1e-4)

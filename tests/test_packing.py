@@ -50,7 +50,7 @@ def test_roundtrip_matches_host_and_coo(packed_case):
     out = np.asarray(spmm_block_ell(
         jnp.asarray(packed.tiles), jnp.asarray(packed.tile_col),
         jnp.asarray(packed.valid), jnp.ones(packed.n_rb, jnp.int32),
-        jnp.asarray(packed.x0), interpret=True))
+        jnp.asarray(packed.x0)))
     host, _ = _subgraph_spmm(sup, x0, np.ones(len(sup), bool))
     coo, rows = _coo_dense_step(sup, packed, x0)
     np.testing.assert_allclose(out[rows][:, :x0.shape[1]], host,
@@ -91,7 +91,7 @@ def test_all_exited_row_block_skip(packed_case):
     out = spmm_block_ell(
         jnp.asarray(packed.tiles), jnp.asarray(packed.tile_col),
         jnp.asarray(packed.valid), jnp.zeros(packed.n_rb, jnp.int32),
-        jnp.asarray(packed.x0), interpret=True)
+        jnp.asarray(packed.x0))
     assert float(jnp.abs(out).max()) == 0.0
 
 
@@ -109,7 +109,7 @@ def test_masked_block_ell_skips_after_batch_exit(packed_case):
         spmm_impl="block_ell",
         ell=(jnp.asarray(packed.tiles), jnp.asarray(packed.tile_col),
              jnp.asarray(packed.valid)),
-        step_active=jnp.asarray(step_active), interpret=True)
+        step_active=jnp.asarray(step_active))
     o = np.asarray(orders)
     assert (o == 1).all()
     assert float(jnp.abs(series[2]).max()) == 0.0
@@ -133,11 +133,11 @@ def test_bucket_floors_are_respected(packed_case):
     out_a = np.asarray(spmm_block_ell(
         jnp.asarray(packed.tiles), jnp.asarray(packed.tile_col),
         jnp.asarray(packed.valid), jnp.ones(packed.n_rb, jnp.int32),
-        jnp.asarray(packed.x0), interpret=True))
+        jnp.asarray(packed.x0)))
     out_b = np.asarray(spmm_block_ell(
         jnp.asarray(bigger.tiles), jnp.asarray(bigger.tile_col),
         jnp.asarray(bigger.valid), jnp.ones(bigger.n_rb, jnp.int32),
-        jnp.asarray(bigger.x0), interpret=True))
+        jnp.asarray(bigger.x0)))
     rows_a = _real_rows(sup, packed)
     rows_b = _real_rows(sup, bigger)
     np.testing.assert_allclose(out_a[rows_a], out_b[rows_b],

@@ -147,7 +147,7 @@ def test_sharded_spmm_reassembles_bit_equal(graph):
         out_base = np.asarray(spmm_block_ell(
             jnp.asarray(base.tiles), jnp.asarray(base.tile_col),
             jnp.asarray(base.valid), jnp.ones(base.n_rb, jnp.int32),
-            jnp.asarray(base.x0), interpret=True))
+            jnp.asarray(base.x0)))
         n_rb_loc = sh.n_rb // D
         parts = []
         for s in range(D):
@@ -156,7 +156,7 @@ def test_sharded_spmm_reassembles_bit_equal(graph):
                 jnp.asarray(sh.tiles[sl]), jnp.asarray(sh.tile_col[sl]),
                 jnp.asarray(sh.valid[sl]),
                 jnp.ones(n_rb_loc, jnp.int32),
-                jnp.asarray(sh.x0), interpret=True)))
+                jnp.asarray(sh.x0))))
         out_sh = np.concatenate(parts, axis=0)
         rowp = shard_row_perm(sh.n_pad, D)
         np.testing.assert_array_equal(out_sh[rowp], out_base)
