@@ -151,8 +151,9 @@ def _distance_exits(out, x_inf, ts2, n_batch):
     """Squared-f32 exit decision over the batch region — the arithmetic
     contract shared with the fused kernel (ts2 < 0 disables exits, since
     d2 >= 0 always)."""
-    d2 = jnp.sum((out[:n_batch] - x_inf) ** 2, axis=1)
-    return d2 < ts2
+    with jax.named_scope("nap.exit"):
+        d2 = jnp.sum((out[:n_batch] - x_inf) ** 2, axis=1)
+        return d2 < ts2
 
 
 class PropagationBackend:
@@ -322,6 +323,18 @@ def pack_operands(backend: PropagationBackend, packed,
 
 
 # ------------------------------------------------------------ the loop
+def _propagate(backend, ops, x_full, node_active, active_rb, ts2,
+               n_batch, n_rows):
+    """One backend step under the ``nap.propagate`` scope, so a device
+    op of the trace maps to its NAP phase by the innermost ``nap.*``
+    scope in its name: the exit distance the step computes outside a
+    kernel is ``nap.exit`` (`_distance_exits`); the fused kernel decides
+    exits inside ``nap.propagate``."""
+    with jax.named_scope("nap.propagate"):
+        return backend.step(ops, x_full, node_active, active_rb, ts2,
+                            n_batch=n_batch, n_rows=n_rows)
+
+
 def _masked_loop(backend, nai, ops, x0, n_batch, n_rows, gather, any_fn):
     """The ONE masked NAP fori-loop (previously triplicated per impl).
 
@@ -352,10 +365,12 @@ def _masked_loop(backend, nai, ops, x0, n_batch, n_rows, gather, any_fn):
         ts2 = jnp.where((l >= nai.t_min) & (l < tmax), ts2_on,
                         jnp.float32(-1.0))
         active_rb = sa[l - 1] * live if sa is not None else None
-        x, exits = backend.step(ops, gather(x), node_active, active_rb,
-                                ts2, n_batch=n_batch, n_rows=n_rows)
-        exit_order = jnp.where((node_active != 0) & exits, l, exit_order)
-        live = any_fn(exit_order == 0)
+        x, exits = _propagate(backend, ops, gather(x), node_active,
+                              active_rb, ts2, n_batch, n_rows)
+        with jax.named_scope("nap.exit"):
+            exit_order = jnp.where((node_active != 0) & exits, l,
+                                   exit_order)
+            live = any_fn(exit_order == 0)
         # cache-hit rows: overwrite whatever the (edge-dropped) step left
         # there with the stored X^(l) values, so the NEXT step's gather
         # reads exact propagated features. Pad ids point one past the row
@@ -454,9 +469,11 @@ def make_superstep(backend: PropagationBackend, nai, *, n_batch: int,
         live = any_fn(exit_order == 0)
         sa = ops.get("step_active")
         active_rb = sa[l - 1] * live if sa is not None else None
-        x, exits = backend.step(ops, gather(x), node_active, active_rb,
-                                ts2, n_batch=nb, n_rows=rows)
-        exit_order = jnp.where((node_active != 0) & exits, l, exit_order)
+        x, exits = _propagate(backend, ops, gather(x), node_active,
+                              active_rb, ts2, nb, rows)
+        with jax.named_scope("nap.exit"):
+            exit_order = jnp.where((node_active != 0) & exits, l,
+                                   exit_order)
         return x, exit_order
 
     if mesh is None:

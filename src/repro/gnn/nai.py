@@ -354,14 +354,16 @@ def make_compiled_infer(cfg: GNNConfig, nai: NAIConfig, *,
         """Per-order classification selected by exit mask — row-wise, so
         it runs unchanged on a shard's local batch rows or the full
         batch."""
-        preds = jnp.zeros(exit_order.shape, jnp.int32)
-        for l in range(1, tmax + 1):
-            # series already carries batch rows only
-            feats = series[:l + 1, :, :cfg.feat_dim]
-            z = apply_classifier(cfg, cls_params[l], feats, l)
-            preds = jnp.where(exit_order == l,
-                              jnp.argmax(z, -1).astype(jnp.int32), preds)
-        return preds
+        with jax.named_scope("nap.classify"):
+            preds = jnp.zeros(exit_order.shape, jnp.int32)
+            for l in range(1, tmax + 1):
+                # series already carries batch rows only
+                feats = series[:l + 1, :, :cfg.feat_dim]
+                z = apply_classifier(cfg, cls_params[l], feats, l)
+                preds = jnp.where(exit_order == l,
+                                  jnp.argmax(z, -1).astype(jnp.int32),
+                                  preds)
+            return preds
 
     @functools.partial(jax.jit, donate_argnums=donate_argnums)
     def run(cls_params, operands, x0, x_inf):

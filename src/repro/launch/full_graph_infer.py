@@ -45,6 +45,15 @@ Failure model (composes with the PR-8 fault machinery):
   steps are recorded as stragglers. The ``superstep_hang`` fault stage
   simulates a hung dispatch through the same retry path.
 
+Observability: the run's `stats` dict is the job's record
+(`repro.obs`). Spans ``offline.pack``, ``offline.upload`` (superstep
+build and operand transfer), ``offline.compute`` and ``offline.fetch``
+(per superstep, with the step), ``offline.ckpt``, ``offline.classify``
+and ``offline.result`` (the result write) add their seconds to it;
+``offline.total`` wraps the whole job, so ``nodes_per_s`` is nodes over
+the job's whole wall time. A finished job's record is published as
+``"offline.job"``.
+
 Deterministic by construction: supersteps are jitted pure functions,
 checkpoint payloads round-trip bit-exactly, classifier params come
 from a seeded init — so interrupted == uninterrupted is exact
@@ -85,6 +94,7 @@ from repro.gnn.packing import shard_batch_perm, step_active_blocks
 from repro.gnn.store import as_store
 from repro.launch.checkpoint import (CheckpointCorruption, CheckpointError,
                                      CheckpointManager)
+from repro.obs import publish, span
 from repro.serving.faults import InjectedFault, WatchdogTimeout
 from repro.sharding.logical import spec
 
@@ -165,13 +175,15 @@ def _make_classifier(cfg, tmax: int):
 
     @jax.jit
     def classify(cls_params, exit_order, series):
-        preds = jnp.zeros(exit_order.shape, jnp.int32)
-        for l in range(1, tmax + 1):
-            feats = series[:l + 1, :, :cfg.feat_dim]
-            z = apply_classifier(cfg, cls_params[l], feats, l)
-            preds = jnp.where(exit_order == l,
-                              jnp.argmax(z, -1).astype(jnp.int32), preds)
-        return preds
+        with jax.named_scope("nap.classify"):
+            preds = jnp.zeros(exit_order.shape, jnp.int32)
+            for l in range(1, tmax + 1):
+                feats = series[:l + 1, :, :cfg.feat_dim]
+                z = apply_classifier(cfg, cls_params[l], feats, l)
+                preds = jnp.where(exit_order == l,
+                                  jnp.argmax(z, -1).astype(jnp.int32),
+                                  preds)
+            return preds
 
     return classify
 
@@ -215,8 +227,21 @@ def run_full_graph_infer(store, cfg, params, nai, ocfg: OfflineConfig,
     stack (`repro.gnn.models`), `nai` the `NAIConfig`, `ocfg` the
     driver knobs. Returns predictions/exit orders for the n REAL nodes
     in store order, plus run stats (resume point, fallbacks, straggler
-    and watchdog counters, checkpoint overhead)."""
-    t_start = time.perf_counter()
+    and watchdog counters, span seconds, checkpoint overhead)."""
+    stats: Dict = {}
+    with span(stats, "offline.total"):
+        predictions, exit_orders = _infer(store, cfg, params, nai, ocfg,
+                                          mesh, fault_plan, stats)
+    stats["nodes_per_s"] = stats["n"] / stats["total_s"]
+    publish("offline.job", stats)
+    return OfflineResult(predictions=predictions,
+                         exit_orders=exit_orders, stats=stats)
+
+
+def _infer(store, cfg, params, nai, ocfg: OfflineConfig, mesh, fault_plan,
+           stats: Dict):
+    """The job behind `run_full_graph_infer`: fills `stats` and returns
+    ``(predictions, exit_orders)``."""
     store = as_store(store)
     mesh = normalize_mesh(mesh)
     D = int(mesh.shape["data"]) if mesh is not None else 1
@@ -226,15 +251,14 @@ def run_full_graph_infer(store, cfg, params, nai, ocfg: OfflineConfig,
                 if fault_plan is not None and not fault_plan.empty
                 else None)
 
-    t0 = time.perf_counter()
-    be, packed = pack_graph(store, D, cfg.r, ocfg.spmm_impl,
-                            halo=gather_mode != "dense", stationary=True)
-    sa = (step_active_blocks(packed.hop_rb, tmax)
-          if be.uses_tiles else None)
-    ops_np = pack_operands(be, packed, sa)
-    if be.uses_dense_x_inf:
-        ops_np["x_inf"] = packed.x_inf
-    pack_s = time.perf_counter() - t0
+    with span(stats, "offline.pack"):
+        be, packed = pack_graph(store, D, cfg.r, ocfg.spmm_impl,
+                                halo=gather_mode != "dense", stationary=True)
+        sa = (step_active_blocks(packed.hop_rb, tmax)
+              if be.uses_tiles else None)
+        ops_np = pack_operands(be, packed, sa)
+        if be.uses_dense_x_inf:
+            ops_np["x_inf"] = packed.x_inf
     nb_pad, n_pad = packed.n_batch, packed.n_pad
 
     fingerprint = {
@@ -251,16 +275,16 @@ def run_full_graph_infer(store, cfg, params, nai, ocfg: OfflineConfig,
     mgr = CheckpointManager(ocfg.ckpt_dir, fingerprint=fingerprint,
                             injector=injector)
 
-    stats: Dict = {
+    stats.update({
         "n": int(store.n), "shards": D, "impl": ocfg.spmm_impl,
         "gather_mode": gather_mode, "t_max": tmax,
         "nb_pad": int(nb_pad), "n_pad": int(n_pad),
         "resumed_from": None, "supersteps_run": 0, "corrupt_steps": 0,
         "fallbacks": [], "ckpt_write_failures": 0,
         "watchdog_retries": 0, "stragglers": [],
-        "pack_s": pack_s, "compute_s": 0.0, "ckpt_s": 0.0,
-        "classify_s": 0.0,
-    }
+        "upload_s": 0.0, "compute_s": 0.0, "fetch_s": 0.0, "ckpt_s": 0.0,
+        "classify_s": 0.0, "result_s": 0.0,
+    })
 
     # ---------------------------------------------------------- resume
     snaps: Dict[int, np.ndarray] = {}   # step -> batch-row state X^(l)
@@ -276,38 +300,26 @@ def run_full_graph_infer(store, cfg, params, nai, ocfg: OfflineConfig,
         x_host = packed.x0
         eo_host = np.zeros(nb_pad, np.int32)
         snaps[0] = x_host[:nb_pad]
-        t0 = time.perf_counter()
-        try:
-            mgr.save_step(0, {"x": x_host, "exit_order": eo_host})
-        except (InjectedFault, CheckpointError, OSError) as e:
-            stats["ckpt_write_failures"] += 1
-            stats["fallbacks"].append(
-                {"step": 0, "error": f"write: {type(e).__name__}: {e}"})
-        stats["ckpt_s"] += time.perf_counter() - t0
+        _save(mgr, 0, x_host, eo_host, stats)
         start = 0
     stats["resumed_from"] = int(start)
 
     # ------------------------------------------------- superstep loop
-    step_fn = make_superstep(be, nai, n_batch=nb_pad, n_rows=n_pad,
-                             mesh=mesh, gather_mode=gather_mode)
-    if mesh is not None:
-        logical = operand_logical(be, gather_mode)
-        ops_dev = {k: jax.device_put(
-            v, NamedSharding(mesh, spec(*logical[k], mesh=mesh)))
-            for k, v in ops_np.items()}
-        row_sh = NamedSharding(mesh, spec("row_shard", None, mesh=mesh))
-        eo_sh = NamedSharding(mesh, spec("row_shard", mesh=mesh))
-
-        def _put(x, eo):
-            return (jax.device_put(np.asarray(x), row_sh),
-                    jax.device_put(np.asarray(eo), eo_sh))
-    else:
-        ops_dev = {k: jnp.asarray(v) for k, v in ops_np.items()}
-
-        def _put(x, eo):
-            return jnp.asarray(x), jnp.asarray(eo)
-
-    x_dev, eo_dev = _put(x_host, eo_host)
+    with span(stats, "offline.upload"):
+        step_fn = make_superstep(be, nai, n_batch=nb_pad, n_rows=n_pad,
+                                 mesh=mesh, gather_mode=gather_mode)
+        if mesh is not None:
+            logical = operand_logical(be, gather_mode)
+            ops_dev = {k: jax.device_put(
+                v, NamedSharding(mesh, spec(*logical[k], mesh=mesh)))
+                for k, v in ops_np.items()}
+            row_sh = NamedSharding(mesh, spec("row_shard", None, mesh=mesh))
+            eo_sh = NamedSharding(mesh, spec("row_shard", mesh=mesh))
+            x_dev = jax.device_put(np.asarray(x_host), row_sh)
+            eo_dev = jax.device_put(np.asarray(eo_host), eo_sh)
+        else:
+            ops_dev = {k: jnp.asarray(v) for k, v in ops_np.items()}
+            x_dev, eo_dev = jnp.asarray(x_host), jnp.asarray(eo_host)
     durations: List[float] = []
     if ocfg.crash_after is not None and ocfg.crash_after <= start:
         raise PreemptionSimulated(
@@ -315,38 +327,11 @@ def run_full_graph_infer(store, cfg, params, nai, ocfg: OfflineConfig,
             f"(crash_after={ocfg.crash_after} already committed)")
 
     for l in range(start + 1, tmax + 1):
-        t0 = time.perf_counter()
-        for attempt in range(ocfg.superstep_retries + 1):
-            last = attempt == ocfg.superstep_retries
-            if injector is not None \
-                    and injector.fire("superstep_hang") is not None:
-                # simulated hung dispatch: the watchdog path declares
-                # the attempt dead and retries deterministically
-                stats["watchdog_retries"] += 1
-                if last:
-                    raise WatchdogTimeout(
-                        f"superstep {l} hung on every attempt "
-                        f"({ocfg.superstep_retries + 1})")
-                continue
-            x_new, eo_new = step_fn(ops_dev, x_dev, eo_dev,
-                                    jnp.int32(l))
-            if ocfg.watchdog_s > 0:
-                deadline = time.monotonic() + ocfg.watchdog_s
-                while not (x_new.is_ready() and eo_new.is_ready()):
-                    if time.monotonic() > deadline:
-                        break
-                    time.sleep(1e-4)
-                if not (x_new.is_ready() and eo_new.is_ready()):
-                    stats["watchdog_retries"] += 1
-                    if last:
-                        raise WatchdogTimeout(
-                            f"superstep {l} exceeded the "
-                            f"{ocfg.watchdog_s}s watchdog on every "
-                            f"attempt")
-                    continue
-            jax.block_until_ready((x_new, eo_new))
-            break
-        dur = time.perf_counter() - t0
+        before = stats["compute_s"]
+        with span(stats, "offline.compute", step=l):
+            x_dev, eo_dev = _superstep(step_fn, ops_dev, x_dev, eo_dev, l,
+                                       ocfg, injector, stats)
+        dur = stats["compute_s"] - before
         if len(durations) >= 2:
             med = statistics.median(durations)
             if dur > ocfg.straggler_factor * med:
@@ -354,56 +339,44 @@ def run_full_graph_infer(store, cfg, params, nai, ocfg: OfflineConfig,
                     {"step": l, "s": round(dur, 6),
                      "median_s": round(med, 6)})
         durations.append(dur)
-        stats["compute_s"] += dur
         stats["supersteps_run"] += 1
-        x_dev, eo_dev = x_new, eo_new
-        x_host = np.asarray(x_dev)
-        eo_host = np.asarray(eo_dev)
-        snaps[l] = x_host[:nb_pad]
-        t0 = time.perf_counter()
-        try:
-            mgr.save_step(l, {"x": x_host, "exit_order": eo_host})
-        except (InjectedFault, CheckpointError, OSError) as e:
-            # tolerated: in-memory state is still good; a crash later
-            # simply resumes from an earlier committed superstep
-            stats["ckpt_write_failures"] += 1
-            stats["fallbacks"].append(
-                {"step": l, "error": f"write: {type(e).__name__}: {e}"})
-        stats["ckpt_s"] += time.perf_counter() - t0
+        with span(stats, "offline.fetch", step=l):
+            x_host = np.asarray(x_dev)
+            eo_host = np.asarray(eo_dev)
+            snaps[l] = x_host[:nb_pad]
+        _save(mgr, l, x_host, eo_host, stats)
         if ocfg.crash_after is not None and l >= ocfg.crash_after:
             raise PreemptionSimulated(
                 f"simulated preemption after superstep {l}")
 
     # -------------------------------------------------- classification
-    t0 = time.perf_counter()
-    eo_final = np.where(eo_host == 0, tmax, eo_host).astype(np.int32)
-    f_pad = packed.x0.shape[1]
-    series = np.stack([snaps[j] for j in range(tmax + 1)])
-    classify = _make_classifier(cfg, tmax)
-    chunk = min(ocfg.cls_chunk, nb_pad)
-    preds = np.empty(nb_pad, np.int32)
-    for lo in range(0, nb_pad, chunk):
-        hi = min(lo + chunk, nb_pad)
-        s_blk = series[:, lo:hi]
-        e_blk = eo_final[lo:hi]
-        if hi - lo < chunk:     # pad the tail to the compiled shape
-            s_blk = np.concatenate(
-                [s_blk, np.zeros((tmax + 1, chunk - (hi - lo), f_pad),
-                                 s_blk.dtype)], axis=1)
-            e_blk = np.concatenate(
-                [e_blk, np.full(chunk - (hi - lo), tmax, np.int32)])
-        out = classify(params["cls"], jnp.asarray(e_blk),
-                       jnp.asarray(s_blk))
-        preds[lo:hi] = np.asarray(out)[:hi - lo]
-    if D > 1:
-        unperm = shard_batch_perm(nb_pad, D)
-        preds = preds[unperm]
-        eo_final = eo_final[unperm]
-    n = store.n
-    predictions = np.ascontiguousarray(preds[:n])
-    exit_orders = np.ascontiguousarray(eo_final[:n])
-    stats["classify_s"] = time.perf_counter() - t0
-
+    with span(stats, "offline.classify"):
+        eo_final = np.where(eo_host == 0, tmax, eo_host).astype(np.int32)
+        f_pad = packed.x0.shape[1]
+        series = np.stack([snaps[j] for j in range(tmax + 1)])
+        classify = _make_classifier(cfg, tmax)
+        chunk = min(ocfg.cls_chunk, nb_pad)
+        preds = np.empty(nb_pad, np.int32)
+        for lo in range(0, nb_pad, chunk):
+            hi = min(lo + chunk, nb_pad)
+            s_blk = series[:, lo:hi]
+            e_blk = eo_final[lo:hi]
+            if hi - lo < chunk:     # pad the tail to the compiled shape
+                s_blk = np.concatenate(
+                    [s_blk, np.zeros((tmax + 1, chunk - (hi - lo), f_pad),
+                                     s_blk.dtype)], axis=1)
+                e_blk = np.concatenate(
+                    [e_blk, np.full(chunk - (hi - lo), tmax, np.int32)])
+            out = classify(params["cls"], jnp.asarray(e_blk),
+                           jnp.asarray(s_blk))
+            preds[lo:hi] = np.asarray(out)[:hi - lo]
+        if D > 1:
+            unperm = shard_batch_perm(nb_pad, D)
+            preds = preds[unperm]
+            eo_final = eo_final[unperm]
+        n = store.n
+        predictions = np.ascontiguousarray(preds[:n])
+        exit_orders = np.ascontiguousarray(eo_final[:n])
     stats["exit_histogram"] = np.bincount(
         exit_orders, minlength=tmax + 1).tolist()
     stats["ckpt_bytes"] = mgr.total_bytes()
@@ -413,15 +386,60 @@ def run_full_graph_infer(store, cfg, params, nai, ocfg: OfflineConfig,
     stats["node_steps_per_s"] = (n * stats["supersteps_run"]
                                  / stats["compute_s"]
                                  if stats["compute_s"] > 0 else 0.0)
-    end_to_end = busy + stats["classify_s"]
-    stats["nodes_per_s"] = n / end_to_end if end_to_end > 0 else 0.0
-    stats["total_s"] = time.perf_counter() - t_start
-    mgr.save_result({"predictions": predictions,
-                     "exit_orders": exit_orders})
+    with span(stats, "offline.result"):
+        mgr.save_result({"predictions": predictions,
+                         "exit_orders": exit_orders})
     if injector is not None:
         stats["injected"] = injector.summary()
-    return OfflineResult(predictions=predictions,
-                         exit_orders=exit_orders, stats=stats)
+    return predictions, exit_orders
+
+
+def _save(mgr: CheckpointManager, l: int, x_host, eo_host, stats: Dict):
+    """Commit superstep `l`'s state. A failed write is tolerated and
+    counted: the in-memory state is still good, and a crash later simply
+    resumes from an earlier committed superstep."""
+    with span(stats, "offline.ckpt", step=l):
+        try:
+            mgr.save_step(l, {"x": x_host, "exit_order": eo_host})
+        except (InjectedFault, CheckpointError, OSError) as e:
+            stats["ckpt_write_failures"] += 1
+            stats["fallbacks"].append(
+                {"step": l, "error": f"write: {type(e).__name__}: {e}"})
+
+
+def _superstep(step_fn, ops_dev, x_dev, eo_dev, l: int, ocfg: OfflineConfig,
+               injector, stats: Dict):
+    """Run superstep `l` to completion, retrying a hung attempt (the
+    ``superstep_hang`` fault or the watchdog) up to
+    ``ocfg.superstep_retries`` times. Returns the new device state."""
+    for attempt in range(ocfg.superstep_retries + 1):
+        last = attempt == ocfg.superstep_retries
+        if injector is not None \
+                and injector.fire("superstep_hang") is not None:
+            # simulated hung dispatch: the watchdog path declares the
+            # attempt dead and retries deterministically
+            stats["watchdog_retries"] += 1
+            if last:
+                raise WatchdogTimeout(
+                    f"superstep {l} hung on every attempt "
+                    f"({ocfg.superstep_retries + 1})")
+            continue
+        x_new, eo_new = step_fn(ops_dev, x_dev, eo_dev, jnp.int32(l))
+        if ocfg.watchdog_s > 0:
+            deadline = time.monotonic() + ocfg.watchdog_s
+            while not (x_new.is_ready() and eo_new.is_ready()):
+                if time.monotonic() > deadline:
+                    break
+                time.sleep(1e-4)
+            if not (x_new.is_ready() and eo_new.is_ready()):
+                stats["watchdog_retries"] += 1
+                if last:
+                    raise WatchdogTimeout(
+                        f"superstep {l} exceeded the {ocfg.watchdog_s}s "
+                        f"watchdog on every attempt")
+                continue
+        jax.block_until_ready((x_new, eo_new))
+        return x_new, eo_new
 
 
 # ----------------------------------------------------------------- CLI

@@ -41,6 +41,16 @@ Two serving modes:
   Completion order stays FIFO, so predictions/exit orders are identical
   to serial serving on the same request stream.
 
+  Each compiled batch keeps one record (`repro.obs`): the spans
+  ``serve.host`` (inside it ``serve.sample``, ``serve.gather``,
+  ``serve.pack``), ``serve.dispatch`` and ``serve.sync`` add their
+  seconds to it, ``hold_s`` is the pipeline hold between dispatch and
+  sync, ``queue_wait_s`` sums its requests' waits from arrival to the
+  start of the host stage, and ``rows_*``/``edges_*`` count real
+  against padded rows and edges. Every span carries the batch's
+  sequence number. The record is appended to `batch_timings` and
+  published as ``"serve.batch"`` when the batch completes.
+
   Operand shapes are bucketed and held at per-batch-size high-water
   marks, so repeat batches hit the jit compile cache; `jit_stats` counts
   compiles vs hits (alarm on compiles in steady state) and `pack_stats`
@@ -111,6 +121,7 @@ from repro.gnn.packing import (CB, PackedSupport, batch_bucket,
 from repro.gnn.propcache import PropCache
 from repro.gnn.sampler import sample_support
 from repro.gnn.store import as_store
+from repro.obs import publish, span
 from repro.serving.faults import (InjectedFault, NaNGuardError,
                                   WatchdogTimeout, poison_results)
 from repro.sharding.logical import spec
@@ -192,6 +203,7 @@ class Request:
     deadline_s: float = float("inf")   # ABSOLUTE completion deadline
     slo_class: str = ""                # routing tier (serving front-end)
     done_s: float = -1.0
+    batched_s: float = -1.0            # start of its batch's host stage
     prediction: int = -1
     exit_order: int = -1
     batch_id: int = -1                 # engine batch this completed in
@@ -281,8 +293,7 @@ class _Inflight:
     nb_real: int             # unique node count (real rows of the result)
     preds_dev: object        # device array futures from the jitted runner
     orders_dev: object
-    host_s: float            # sample + pack wall time
-    dispatch_s: float        # operand transfer + async dispatch wall time
+    rec: dict                # the batch's record (repro.obs)
     t_submit: float = 0.0    # wall clock at dispatch (watchdog anchor)
     series_dev: object = None   # (T_max+1, nb, f) batch-row series future
     fill: object = None      # cache fill record (nodes, deps, gv) or None
@@ -347,8 +358,9 @@ class NAIServingEngine:
         # -> (s_bucket, tb_bucket, e_bucket, h_bucket, hb_bucket, k_bucket)
         self.jit_stats: Dict[str, int] = {"compiles": 0, "hits": 0}
         self.pack_stats: Dict[str, int] = {"allocs": 0, "reuses": 0}
-        # per-batch stage breakdown (host/dispatch/sync seconds), bounded
+        # per-batch records (stage seconds and counters), bounded
         self.batch_timings: Deque[Dict[str, float]] = deque(maxlen=1024)
+        self._batch_seq = 0      # sequence number of the next batch
         self._runner = None
         self._bucket_hwm: Dict[int, Tuple[int, ...]] = {}
         self._seen_keys: set = set()
@@ -447,13 +459,14 @@ class NAIServingEngine:
                 if self._runner is not None else ())
 
     # ------------------------------------------------------- host stage
-    def _host_stage(self, nodes: np.ndarray):
+    def _host_stage(self, nodes: np.ndarray, rec: dict):
         """Sample the support and pack it into a pooled buffer set,
         plus the static per-step row-block predicate for the Pallas
         impls. `nodes` must be duplicate-free. Pure host work — no jax
         calls, and no full-graph arrays: everything reads through the
         store's row-gather view API, so an `MmapStore` only pages in the
-        support's rows.
+        support's rows. The sampler, feature gather and packing spans
+        and the real/padded row and edge counts go to the batch's `rec`.
 
         Returns ``(packed, step_active, fill)``: `fill` is the
         propagated-feature-cache fill record (batch nodes, dependency
@@ -462,27 +475,45 @@ class NAIServingEngine:
         cache off."""
         store, cfg, nai = self.store, self.cfg, self.nai
         be = self._backend
-        sup = sample_support(store, nodes, nai.t_max, cfg.r,
-                             cache=self.cache)
+        seq = rec["batch"]
+        with span(rec, "serve.sample", batch=seq):
+            sup = sample_support(store, nodes, nai.t_max, cfg.r,
+                                 cache=self.cache)
         nb = sup.n_batch
         n_hit = int(sup.hit.sum()) if sup.hit is not None else 0
         self.row_stats["rows_support"] += len(sup)
         self.row_stats["rows_packed"] += len(sup) - n_hit
-        x0 = store.gather_features(sup.nodes).astype(np.float32)
-        # dense x_inf is built from the f32 factors so the fused kernel
-        # (which streams the factors and multiplies in f32) is
-        # bit-consistent with the dense block_ell/segment distance; in
-        # fused mode the dense matrix is never materialized at all —
-        # a zero-column placeholder carries just the batch-row count
-        c_inf, s_inf = support_stationary_factors(store, sup, x0, cfg.r)
-        c_inf = c_inf.astype(np.float32)
-        s_inf = s_inf.astype(np.float32)
-        if be.uses_dense_x_inf:
-            x_inf = c_inf[:, None] * s_inf[None, :]
-        else:
-            x_inf = np.zeros((nb, 0), np.float32)
+        with span(rec, "serve.gather", batch=seq):
+            x0 = store.gather_features(sup.nodes).astype(np.float32)
+            # dense x_inf is built from the f32 factors so the fused
+            # kernel (which streams the factors and multiplies in f32) is
+            # bit-consistent with the dense block_ell/segment distance;
+            # in fused mode the dense matrix is never materialized at
+            # all — a zero-column placeholder carries the batch-row count
+            c_inf, s_inf = support_stationary_factors(store, sup, x0, cfg.r)
+            c_inf = c_inf.astype(np.float32)
+            s_inf = s_inf.astype(np.float32)
+            if be.uses_dense_x_inf:
+                x_inf = c_inf[:, None] * s_inf[None, :]
+            else:
+                x_inf = np.zeros((nb, 0), np.float32)
+        with span(rec, "serve.pack", batch=seq):
+            packed, step_active = self._pack(sup, x0, x_inf, c_inf, s_inf)
+        rec.update(rows_real=packed.s_real, rows_pad=packed.n_pad,
+                   edges_real=len(sup.src), edges_pad=packed.src.size)
+        fill = None
+        if self.cache is not None and self.cache_fill:
+            # the full support node set is the conservative dependency
+            # cone of every batch row's series (see PropCache.fill)
+            fill = (nodes, sup.nodes, sup.graph_version)
+        return packed, step_active, fill
 
-        nb_bucket = batch_bucket(nb, self.n_shards)
+    def _pack(self, sup, x0, x_inf, c_inf, s_inf):
+        """Pack the sampled support into the bucket's next pooled buffer
+        set, raise the bucket's high-water marks and count the shape as
+        a jit hit or compile. Returns ``(packed, step_active)``."""
+        nai, be = self.nai, self._backend
+        nb_bucket = batch_bucket(sup.n_batch, self.n_shards)
         hwm = self._bucket_hwm.get(nb_bucket, (0, 0, 0, 0, 0, 0))
         slots = self._pack_pool.setdefault(
             nb_bucket, [None] * (self.pipeline_depth + 1))
@@ -531,12 +562,7 @@ class NAIServingEngine:
             self.jit_stats["compiles"] += 1
         step_active = (step_active_blocks(packed.hop_rb, nai.t_max)
                        if be.uses_tiles else None)
-        fill = None
-        if self.cache is not None and self.cache_fill:
-            # the full support node set is the conservative dependency
-            # cone of every batch row's series (see PropCache.fill)
-            fill = (nodes, sup.nodes, sup.graph_version)
-        return packed, step_active, fill
+        return packed, step_active
 
     # ----------------------------------------------------- device stage
     def _device_stage(self, packed: PackedSupport,
@@ -656,30 +682,32 @@ class NAIServingEngine:
         pipeline depth. A sync failure, watchdog trip, or guard trip
         fails ONLY this batch — the slot is released either way."""
         fl = self._inflight.popleft()
-        t0 = time.perf_counter()
-        try:
-            self._watchdog_sync(fl)
-            preds_a = np.asarray(fl.preds_dev)
-            orders_a = np.asarray(fl.orders_dev)
-            self._guard_results(preds_a, orders_a, fl.nb_real)
-        except Exception as e:   # noqa: BLE001 — batch-level isolation
-            return self._fail_batch(fl.requests, e)
-        if fl.fill is not None:
-            # fill only after the guards pass — a poisoned/hung batch
-            # must not seed future batches. Steps 1..T_max of a batch
-            # row are exact global values (hop 0, full budget), so the
-            # whole series is insertable.
-            batch_nodes, dep_nodes, gv = fl.fill
-            series = np.asarray(fl.series_dev)
-            self.cache.fill(
-                self.store, batch_nodes,
-                series[1:, :fl.nb_real].transpose(1, 0, 2), dep_nodes, gv)
-        preds = preds_a[:fl.nb_real][fl.inv]
-        orders = orders_a[:fl.nb_real][fl.inv]
+        rec = fl.rec
+        with span(rec, "serve.sync", batch=rec["batch"]):
+            rec["hold_s"] = time.perf_counter() - fl.t_submit
+            try:
+                self._watchdog_sync(fl)
+                preds_a = np.asarray(fl.preds_dev)
+                orders_a = np.asarray(fl.orders_dev)
+                self._guard_results(preds_a, orders_a, fl.nb_real)
+            except Exception as e:   # noqa: BLE001 — batch-level isolation
+                return self._fail_batch(fl.requests, e)
+            if fl.fill is not None:
+                # fill only after the guards pass — a poisoned/hung batch
+                # must not seed future batches. Steps 1..T_max of a batch
+                # row are exact global values (hop 0, full budget), so the
+                # whole series is insertable.
+                batch_nodes, dep_nodes, gv = fl.fill
+                series = np.asarray(fl.series_dev)
+                self.cache.fill(
+                    self.store, batch_nodes,
+                    series[1:, :fl.nb_real].transpose(1, 0, 2), dep_nodes,
+                    gv)
+            preds = preds_a[:fl.nb_real][fl.inv]
+            orders = orders_a[:fl.nb_real][fl.inv]
         done = time.perf_counter()
-        self.batch_timings.append({
-            "host_s": fl.host_s, "dispatch_s": fl.dispatch_s,
-            "sync_s": done - t0, "n": len(fl.requests)})
+        self.batch_timings.append(rec)
+        publish("serve.batch", rec)
         self._complete(fl.requests, preds, orders, done)
         return fl.requests
 
@@ -804,6 +832,11 @@ class NAIServingEngine:
             raise InjectedFault("injected host-stage failure")
 
     def _serve_batch(self, batch: List[Request]) -> List[Request]:
+        seq = self._batch_seq
+        self._batch_seq += 1
+        t0 = time.perf_counter()
+        for r in batch:
+            r.batched_s = t0
         nodes = np.asarray([r.node_id for r in batch])
         # dedupe per batch (client retries): the sampler requires
         # duplicate-free batches — duplicated rows would double-count in
@@ -818,27 +851,28 @@ class NAIServingEngine:
                 return self._fail_batch(batch, e)
             self._complete(batch, p_u[inv], o_u[inv], time.perf_counter())
             return batch
-        t0 = time.perf_counter()
+        rec = {"batch": seq, "n": len(batch),
+               "queue_wait_s": sum(t0 - r.arrival_s for r in batch)}
         try:
-            self._inject_host_faults()
-            packed, step_active, fill = self._host_stage(uniq)
-            t1 = time.perf_counter()
-            if (self._faults is not None
-                    and self._faults.fire("device") is not None):
-                raise InjectedFault("injected device-stage failure")
-            preds_dev, orders_dev, series_dev = self._device_stage(
-                packed, step_active)
-            preds_dev, orders_dev = poison_results(self._faults,
-                                                   preds_dev, orders_dev)
+            with span(rec, "serve.host", batch=seq):
+                self._inject_host_faults()
+                packed, step_active, fill = self._host_stage(uniq, rec)
+            with span(rec, "serve.dispatch", batch=seq):
+                if (self._faults is not None
+                        and self._faults.fire("device") is not None):
+                    raise InjectedFault("injected device-stage failure")
+                preds_dev, orders_dev, series_dev = self._device_stage(
+                    packed, step_active)
+                preds_dev, orders_dev = poison_results(
+                    self._faults, preds_dev, orders_dev)
         except Exception as e:   # noqa: BLE001 — batch-level isolation:
             # a stage failure takes down THIS batch only; in-flight
             # batches and the queue are untouched, and _advance keeps
             # the pipeline moving
             return self._fail_batch(batch, e) + self._advance()
-        t2 = time.perf_counter()
         self._inflight.append(
             _Inflight(batch, inv, packed.nb_real, preds_dev, orders_dev,
-                      host_s=t1 - t0, dispatch_s=t2 - t1, t_submit=t2,
+                      rec, t_submit=time.perf_counter(),
                       series_dev=series_dev, fill=fill))
         done: List[Request] = []
         while len(self._inflight) >= self.pipeline_depth:
