@@ -34,12 +34,24 @@ Two serving modes:
   With ``pipeline_depth=1`` the two stages run back to back per batch
   (serial serving, the pre-pipeline behavior). With ``pipeline_depth=2``
   the engine keeps one batch in flight: batch N+1's sampling/packing
-  (host stage) overlaps batch N's device compute, and batch N's results
-  are only synced (`np.asarray`) once batch N+1 has been submitted.
-  `step()` then returns the *previous* batch's completed requests (and
-  `[]` while the pipe fills); `flush()` drains what remains in flight.
+  (host stage) overlaps batch N's device compute. Each dispatched batch
+  goes to the engine's **completion waiter**, one thread that, in FIFO
+  order, blocks until the batch's device results are ready, copies and
+  guards them, and delivers the answers on the `Request` objects
+  (`prediction`, `exit_order`, ``status="completed"``, `done_s`) and to
+  each request's `on_done` callback — so a client that registers one
+  gets batch N's answers when the device finishes, not after batch
+  N+1's host stage. The engine thread still finalizes batch N once
+  batch N+1 has been submitted (stats, records, cache fill, failures):
+  `step()` then returns the *previous* batch's requests (and `[]`
+  while the pipe fills); `flush()` drains what remains in flight.
   Completion order stays FIFO, so predictions/exit orders are identical
   to serial serving on the same request stream.
+
+  Delivery contract: a request's `done_s` is the moment its answer (or
+  its failure) is handed out — its `on_done` callback runs right after
+  the stamp, at every depth and in both modes. Failed batches are
+  declared on the engine thread at finalize, never by the waiter.
 
   Each compiled batch keeps one record (`repro.obs`): the spans
   ``serve.host`` (inside it ``serve.sample``, ``serve.gather``,
@@ -48,8 +60,12 @@ Two serving modes:
   sync, ``queue_wait_s`` sums its requests' waits from arrival to the
   start of the host stage, and ``rows_*``/``edges_*`` count real
   against padded rows and edges. Every span carries the batch's
-  sequence number. The record is appended to `batch_timings` and
-  published as ``"serve.batch"`` when the batch completes.
+  sequence number. The hold ends when the device results are ready,
+  and ``serve.sync`` is the copy, the guards and the delivery (on the
+  waiter when pipelined); ``early`` is 1 when the answers were delivered
+  before the engine thread reached the batch's finalize, and ``lead_s``
+  is by how long (else 0; always 0 when serial). The record is appended to `batch_timings` and
+  published as ``"serve.batch"`` when the batch is finalized.
 
   Operand shapes are bucketed and held at per-batch-size high-water
   marks, so repeat batches hit the jit compile cache; `jit_stats` counts
@@ -102,7 +118,8 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Deque, Dict, List, Optional, Tuple
+from concurrent.futures import Future, ThreadPoolExecutor
+from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -125,6 +142,10 @@ from repro.obs import publish, span
 from repro.serving.faults import (InjectedFault, NaNGuardError,
                                   WatchdogTimeout, poison_results)
 from repro.sharding.logical import spec
+
+# longest pause between the watchdog's readiness polls (~ the
+# interpreter's default thread switch interval)
+_WATCH_POLL_MAX_S = 5e-3
 
 
 @dataclasses.dataclass(frozen=True)
@@ -215,6 +236,15 @@ class Request:
     retried: bool = False              # recovered via the reference path
     degraded: bool = False             # demoted by an open circuit breaker
     probe: bool = False                # half-open breaker probe request
+    # delivery callback: called once with the request as soon as it is
+    # terminal ("completed" or "failed"), right after `done_s` is
+    # stamped — `done_s` is the moment of this hand-off. Pipelined
+    # compiled serving calls it on the engine's completion waiter
+    # thread, often before `step`/`poll` return the request; otherwise
+    # on the thread that runs `step`/`poll`/`flush`. It must not call
+    # back into the engine; an exception it raises is kept in `error`
+    on_done: Optional[Callable[["Request"], None]] = dataclasses.field(
+        default=None, compare=False, repr=False)
 
     @property
     def within_deadline(self) -> bool:
@@ -263,6 +293,7 @@ class EngineStats:
     batches: int = 0
     failed: int = 0        # requests that ended status="failed"
     retried: int = 0       # requests recovered on the reference path
+    fill_errors: int = 0   # cache fills that raised (insert skipped)
     latencies: LatencyRing = dataclasses.field(default_factory=LatencyRing)
     exit_hist: Dict[int, int] = dataclasses.field(default_factory=dict)
 
@@ -287,7 +318,7 @@ class EngineStats:
 
 @dataclasses.dataclass
 class _Inflight:
-    """One submitted batch whose device results have not been synced."""
+    """One submitted batch that has not been finalized."""
     requests: List[Request]
     inv: np.ndarray          # dedupe inverse map (batch -> unique row)
     nb_real: int             # unique node count (real rows of the result)
@@ -297,6 +328,10 @@ class _Inflight:
     t_submit: float = 0.0    # wall clock at dispatch (watchdog anchor)
     series_dev: object = None   # (T_max+1, nb, f) batch-row series future
     fill: object = None      # cache fill record (nodes, deps, gv) or None
+    # pipeline_depth >= 2: the completion waiter's future, resolving to
+    # the delivery time once it has handed out the answers (else None:
+    # the engine thread syncs)
+    delivery: Optional[Future] = None
 
 
 class NAIServingEngine:
@@ -365,6 +400,9 @@ class NAIServingEngine:
         self._bucket_hwm: Dict[int, Tuple[int, ...]] = {}
         self._seen_keys: set = set()
         self._inflight: Deque[_Inflight] = deque()
+        # completion waiter (pipeline_depth >= 2), started with the first
+        # pipelined batch and shut down by close()
+        self._waiter: Optional[ThreadPoolExecutor] = None
         # rotating pack-buffer pool: bucket -> pipeline_depth + 1 slots
         self._pack_pool: Dict[int, List[Optional[PackedSupport]]] = {}
         self._pool_idx: Dict[int, int] = {}
@@ -429,10 +467,14 @@ class NAIServingEngine:
             self.cache.reset_stats()
 
     def close(self) -> None:
-        """Drain in-flight work, then release the store's OS resources
-        (fd/maps for `MmapStore`). Idempotent — front-ends sharing one
-        store across per-class engines close it once per engine."""
+        """Drain in-flight work, stop the completion waiter, then release
+        the store's OS resources (fd/maps for `MmapStore`). Idempotent —
+        front-ends sharing one store across per-class engines close it
+        once per engine."""
         self.flush()
+        if self._waiter is not None:
+            self._waiter.shutdown()
+            self._waiter = None
         self.store.close()
 
     def pooled_bytes(self) -> Dict[str, int]:
@@ -602,11 +644,18 @@ class NAIServingEngine:
         `WatchdogTimeout` — the batch is declared hung and failed, and
         the pipeline slot it held is free again (re-armed). With the
         watchdog off (None) this returns immediately and the sync
-        blocks, exactly the pre-watchdog behavior."""
+        blocks, exactly the pre-watchdog behavior.
+
+        The pause between polls doubles from 100 us up to
+        `_WATCH_POLL_MAX_S`, so a long device stage wakes the polling
+        thread a few hundred times a second, not ten thousand: on the
+        completion waiter each wake-up takes the interpreter lock from
+        the engine thread's host stage."""
         wd = self.watchdog_s
         if wd is None:
             return
         deadline = fl.t_submit + wd
+        pause = 1e-4
         for dev in (fl.preds_dev, fl.orders_dev):
             ready = getattr(dev, "is_ready", None)
             if ready is None:
@@ -617,7 +666,8 @@ class NAIServingEngine:
                         f"device sync not ready {wd * 1e3:.0f} ms after "
                         f"dispatch; batch of {len(fl.requests)} declared "
                         f"hung")
-                time.sleep(1e-4)
+                time.sleep(pause)
+                pause = min(2.0 * pause, _WATCH_POLL_MAX_S)
 
     def _guard_results(self, preds: np.ndarray, orders: np.ndarray,
                        nb_real: int) -> None:
@@ -666,63 +716,126 @@ class NAIServingEngine:
                 self.stats.retried += len(batch)
                 self._complete(batch, p_u[inv], o_u[inv],
                                time.perf_counter())
+                self._account(batch)
                 return batch
         msg = f"{type(err).__name__}: {err}"
         for r in batch:
             r.status = "failed"
             r.error = msg
             r.done_s = time.perf_counter()
+        self._notify(batch)
         self.stats.failed += len(batch)
         return batch
 
+    def _fetch(self, fl: _Inflight):
+        """Copy a batch's ready device results to the host, guard them,
+        and map them back to the batch's requests. Raises on a sync or
+        guard failure. Returns ``(preds, orders)`` in request order."""
+        preds_a = np.asarray(fl.preds_dev)
+        orders_a = np.asarray(fl.orders_dev)
+        self._guard_results(preds_a, orders_a, fl.nb_real)
+        return (preds_a[:fl.nb_real][fl.inv],
+                orders_a[:fl.nb_real][fl.inv])
+
+    def _await_and_deliver(self, fl: _Inflight) -> float:
+        """Sync one batch and deliver its answers: block until the device
+        results are ready — a plain `block_until_ready`, which releases
+        the interpreter lock, or the watchdog's bounded wait when armed —
+        then copy, guard and hand the answers to the batch's requests
+        (`_complete`). Pipelined, the completion waiter runs this on its
+        own thread in FIFO order; serial, the engine thread runs it at
+        finalize. A batch whose sync or guards fail gets nothing
+        delivered: the error is raised to the finalize. Returns the
+        delivery time."""
+        rec = fl.rec
+        if self.watchdog_s is None:
+            jax.block_until_ready((fl.preds_dev, fl.orders_dev))
+        else:
+            self._watchdog_sync(fl)
+        rec["hold_s"] = time.perf_counter() - fl.t_submit
+        with span(rec, "serve.sync", batch=rec["batch"]):
+            preds, orders = self._fetch(fl)
+            done = time.perf_counter()
+            self._complete(fl.requests, preds, orders, done)
+        return done
+
+    def _fill_cache(self, fl: _Inflight) -> None:
+        """Insert a delivered batch's series into the propagated-feature
+        cache (no-op without a fill record). Called only after the guards
+        pass — a poisoned/hung batch must not seed future batches. Steps
+        1..T_max of a batch row are exact global values (hop 0, full
+        budget), so the whole series is insertable. The answers are out
+        already, so a fill that raises only skips the insert (each row
+        it did insert is whole) and is counted in `stats.fill_errors`."""
+        if fl.fill is None:
+            return
+        batch_nodes, dep_nodes, gv = fl.fill
+        try:
+            series = np.asarray(fl.series_dev)
+            self.cache.fill(
+                self.store, batch_nodes,
+                series[1:, :fl.nb_real].transpose(1, 0, 2), dep_nodes, gv)
+        except Exception:   # noqa: BLE001 — the batch is already answered
+            self.stats.fill_errors += 1
+
     def _finalize_oldest(self) -> List[Request]:
-        """Sync the oldest in-flight batch (block on its device results,
-        bounded by the watchdog when armed) and complete its requests.
-        FIFO, so completion order matches submission order regardless of
-        pipeline depth. A sync failure, watchdog trip, or guard trip
+        """Finalize the oldest in-flight batch: take its delivered answers,
+        then keep stats, the record, the cache fill and the returned
+        requests. FIFO, so completion order matches submission order
+        regardless of pipeline depth. Pipelined, the completion waiter
+        has synced the batch and delivered its answers, usually long
+        before this point, and this joins it; serial, the sync and the
+        delivery run here. A sync failure, watchdog trip, or guard trip
         fails ONLY this batch — the slot is released either way."""
         fl = self._inflight.popleft()
         rec = fl.rec
-        with span(rec, "serve.sync", batch=rec["batch"]):
-            rec["hold_s"] = time.perf_counter() - fl.t_submit
-            try:
-                self._watchdog_sync(fl)
-                preds_a = np.asarray(fl.preds_dev)
-                orders_a = np.asarray(fl.orders_dev)
-                self._guard_results(preds_a, orders_a, fl.nb_real)
-            except Exception as e:   # noqa: BLE001 — batch-level isolation
-                return self._fail_batch(fl.requests, e)
-            if fl.fill is not None:
-                # fill only after the guards pass — a poisoned/hung batch
-                # must not seed future batches. Steps 1..T_max of a batch
-                # row are exact global values (hop 0, full budget), so the
-                # whole series is insertable.
-                batch_nodes, dep_nodes, gv = fl.fill
-                series = np.asarray(fl.series_dev)
-                self.cache.fill(
-                    self.store, batch_nodes,
-                    series[1:, :fl.nb_real].transpose(1, 0, 2), dep_nodes,
-                    gv)
-            preds = preds_a[:fl.nb_real][fl.inv]
-            orders = orders_a[:fl.nb_real][fl.inv]
-        done = time.perf_counter()
+        reached = time.perf_counter()
+        try:
+            done = (self._await_and_deliver(fl) if fl.delivery is None
+                    else fl.delivery.result())
+        except Exception as e:   # noqa: BLE001 — batch-level isolation
+            return self._fail_batch(fl.requests, e)
+        rec["early"] = int(done < reached)
+        rec["lead_s"] = max(reached - done, 0.0)
+        self._fill_cache(fl)
         self.batch_timings.append(rec)
         publish("serve.batch", rec)
-        self._complete(fl.requests, preds, orders, done)
+        self._account(fl.requests)
         return fl.requests
 
     def _complete(self, batch: List[Request], preds, orders,
                   done: float) -> None:
-        bid = self.stats.batches
+        """Hand a batch's answers to its requests — what a client reads,
+        and what its `on_done` callback is given. Pipelined, the
+        completion waiter calls this; `_account` then counts the batch
+        on the engine thread."""
         for r, p, o in zip(batch, preds, orders):
             r.done_s = done
             r.prediction = int(p)
             r.exit_order = int(o)
-            r.batch_id = bid
             r.status = "completed"
-            self.stats.latencies.append(done - r.arrival_s)
-            self.stats.exit_hist[int(o)] = \
-                self.stats.exit_hist.get(int(o), 0) + 1
+        self._notify(batch)
+
+    @staticmethod
+    def _notify(batch: List[Request]) -> None:
+        """Call the terminal requests' `on_done` callbacks. A callback
+        that raises is the client's fault, not the batch's: its error is
+        kept on its request and the others still run."""
+        for r in batch:
+            if r.on_done is not None:
+                try:
+                    r.on_done(r)
+                except Exception as e:   # noqa: BLE001 — client code
+                    r.error = f"on_done: {type(e).__name__}: {e}"
+
+    def _account(self, batch: List[Request]) -> None:
+        """Count a delivered batch into the engine's stats."""
+        bid = self.stats.batches
+        for r in batch:
+            r.batch_id = bid
+            self.stats.latencies.append(r.done_s - r.arrival_s)
+            self.stats.exit_hist[r.exit_order] = \
+                self.stats.exit_hist.get(r.exit_order, 0) + 1
         self.stats.served += len(batch)
         self.stats.batches += 1
 
@@ -790,9 +903,12 @@ class NAIServingEngine:
 
         `opportunistic=True` (the front-end's `poll`) additionally
         finalizes in-flight batches whose device results are ALREADY
-        complete — `jax.Array.is_ready` makes that a non-blocking check,
-        so completions surface promptly during arrival lulls without
-        ever stalling on unfinished device work."""
+        complete and delivered — `jax.Array.is_ready` and the waiter's
+        future make that a non-blocking check, so they leave the
+        pipeline promptly during arrival lulls without ever stalling on
+        unfinished device work. Their answers wait for neither path: the
+        completion waiter delivers them as soon as the device results
+        are ready (`_await_and_deliver`)."""
         done: List[Request] = []
         while len(self._inflight) >= self.pipeline_depth:
             done += self._finalize_oldest()
@@ -801,10 +917,13 @@ class NAIServingEngine:
                 # no is_ready attribute means the results are already
                 # host-materialized (plain arrays), i.e. trivially ready
                 # — treating that as NOT ready parks the batch below
-                # pipeline_depth where poll() can never finalize it
-                ready = getattr(self._inflight[0].preds_dev,
-                                "is_ready", None)
-                if ready is not None and not ready():
+                # pipeline_depth where poll() can never finalize it.
+                # Device results leave once ready AND delivered by the
+                # waiter, so poll never waits on the copy either
+                head = self._inflight[0]
+                ready = getattr(head.preds_dev, "is_ready", None)
+                if ready is not None and not (ready()
+                                              and head.delivery.done()):
                     break
                 done += self._finalize_oldest()
         # watchdog re-arm: a hung head batch must not wedge open-loop
@@ -850,6 +969,7 @@ class NAIServingEngine:
             except Exception as e:   # noqa: BLE001 — batch isolation
                 return self._fail_batch(batch, e)
             self._complete(batch, p_u[inv], o_u[inv], time.perf_counter())
+            self._account(batch)
             return batch
         rec = {"batch": seq, "n": len(batch),
                "queue_wait_s": sum(t0 - r.arrival_s for r in batch)}
@@ -870,10 +990,15 @@ class NAIServingEngine:
             # batches and the queue are untouched, and _advance keeps
             # the pipeline moving
             return self._fail_batch(batch, e) + self._advance()
-        self._inflight.append(
-            _Inflight(batch, inv, packed.nb_real, preds_dev, orders_dev,
-                      rec, t_submit=time.perf_counter(),
-                      series_dev=series_dev, fill=fill))
+        fl = _Inflight(batch, inv, packed.nb_real, preds_dev, orders_dev,
+                       rec, t_submit=time.perf_counter(),
+                       series_dev=series_dev, fill=fill)
+        if self.pipeline_depth > 1:
+            if self._waiter is None:
+                self._waiter = ThreadPoolExecutor(
+                    1, thread_name_prefix="nai-waiter")
+            fl.delivery = self._waiter.submit(self._await_and_deliver, fl)
+        self._inflight.append(fl)
         done: List[Request] = []
         while len(self._inflight) >= self.pipeline_depth:
             done += self._finalize_oldest()

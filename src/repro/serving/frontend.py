@@ -31,7 +31,8 @@ into the latter:
   one (cheap, fast) — while the {1,2,3}·2^k bucket policy keeps each
   engine's compiled-shape set small. A request's class picks its
   engine; its deadline (class default or per-request override) is
-  carried on the `Request` and scored at completion.
+  carried on the `Request` and scored at completion: against `done_s`,
+  the moment the answer is handed to the request's `on_done` callback.
 
 **Goodput** — answers delivered within their deadline — is the
 front-end's currency: `ClassStats` counts offered / accepted / rejected
@@ -51,7 +52,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.gnn.nai import NAIConfig
 from repro.serving.engine import (EngineConfig, NAIServingEngine, Request)
@@ -303,12 +304,18 @@ class ServingFrontend:
     # ---------------------------------------------------------- ingress
     def submit(self, node_id: int, slo_class: Optional[str] = None,
                now: Optional[float] = None,
-               budget_s: Optional[float] = None) -> Optional[Request]:
+               budget_s: Optional[float] = None,
+               on_done: Optional[Callable[[Request], None]] = None
+               ) -> Optional[Request]:
         """Route one request into its class lane. Returns the `Request`
         if accepted, None if shed by backpressure (lane at
         `queue_depth`). ``budget_s`` overrides the class's default
         latency budget; the absolute deadline is stamped on the request
-        as ``arrival + budget``."""
+        as ``arrival + budget``. ``on_done`` is the request's delivery
+        callback (`Request.on_done`): the engine calls it with the
+        terminal request at `done_s`, the instant its deadline is scored
+        against — pipelined, that is before `step` returns it. A shed
+        request is never accepted and gets no call."""
         name = self.default_class if slo_class is None else slo_class
         if name not in self.classes:
             raise KeyError(f"unknown SLO class {name!r} "
@@ -343,7 +350,8 @@ class ServingFrontend:
             return None
         budget = c.deadline_s if budget_s is None else budget_s
         req = Request(nid, now, deadline_s=now + budget,
-                      slo_class=name, probe=probe, degraded=degraded)
+                      slo_class=name, probe=probe, degraded=degraded,
+                      on_done=on_done)
         eng.submit_request(req)
         st.accepted += 1
         if degraded:

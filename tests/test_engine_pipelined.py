@@ -4,6 +4,9 @@ serving on the same request stream, zero steady-state jit compiles (the
 batch-row series carry must not add a shape axis that defeats bucketing),
 zero steady-state bucket-sized pack allocations, and bounded stats."""
 import dataclasses
+import sys
+import threading
+import time
 
 import jax
 import numpy as np
@@ -12,7 +15,8 @@ import pytest
 from repro.gnn import GNNConfig, init_classifiers, load_dataset
 from repro.gnn.nai import NAIConfig, _needed_mask
 from repro.gnn.sampler import sample_support
-from repro.serving import NAIServingEngine
+from repro.serving import NAIServingEngine, Request
+from repro.serving import engine as engine_mod
 from repro.serving.engine import EngineStats, LatencyRing
 from repro.gnn.store import as_store
 
@@ -125,6 +129,246 @@ def test_step_on_empty_queue_keeps_pipeline(setup, stream):
     done = eng.flush()                   # explicit drain
     assert len(done) == len(stream[1])
     assert not eng._inflight
+
+
+def _requests(nodes, now=0.0):
+    return [Request(int(n), now) for n in nodes]
+
+
+def test_waiter_delivers_before_the_next_dispatch(setup, stream,
+                                                  monkeypatch):
+    """At depth 2 batch 0's answers are on its requests while batch 1's
+    host stage still runs; `step()` returns them only at batch 1's
+    finalize. Batch 1's host stage is held until batch 0's delivery
+    future has resolved, and looks at batch 0's requests from inside."""
+    g, cfg, params, nai = setup
+    eng = NAIServingEngine(cfg, nai, params, g, max_wait_s=10.0,
+                           mode="compiled", spmm_impl="segment",
+                           pipeline_depth=2)
+    seen = {}
+
+    def slow_sample(*args, **kwargs):
+        if eng._inflight:               # batch 1's host stage
+            eng._inflight[0].delivery.result(timeout=60)
+            seen["t"] = time.perf_counter()
+            seen["b0"] = [(r.status, r.done_s, r.batch_id) for r in b0]
+        return sample_support(*args, **kwargs)
+
+    monkeypatch.setattr(engine_mod, "sample_support", slow_sample)
+    b0, b1 = _requests(stream[0]), _requests(stream[1])
+    for r in b0:
+        eng.submit_request(r)
+    assert eng.step() == []              # batch 0 in flight
+    for r in b1:
+        eng.submit_request(r)
+    done = eng.step()                    # batch 1 dispatched: 0 finalized
+    assert done == b0
+    # inside batch 1's host stage batch 0 was answered, not yet finalized
+    assert [st for st, _, _ in seen["b0"]] == ["completed"] * len(b0)
+    assert [bid for _, _, bid in seen["b0"]] == [-1] * len(b0)
+    assert [r.done_s for r in b0] == [t for _, t, _ in seen["b0"]]
+    assert all(0.0 < r.done_s <= seen["t"] for r in b0)
+    assert all(r.prediction >= 0 and r.batch_id == 0 for r in b0)
+    rec0 = eng.batch_timings[0]
+    assert rec0["early"] == 1 and rec0["lead_s"] > 0.0
+    eng._inflight[0].delivery.result(timeout=60)
+    assert eng.step() == []              # delivered, not yet finalized
+    assert all(r.status == "completed" and r.batch_id == -1 for r in b1)
+    assert eng.flush() == b1
+    assert all(r.batch_id == 1 for r in b1)
+
+
+def test_poll_finalizes_a_delivered_batch(setup, stream):
+    """On a quiet tick `poll` takes a batch out of the pipeline once the
+    waiter has delivered it; its record counts it as early."""
+    g, cfg, params, nai = setup
+    eng = NAIServingEngine(cfg, nai, params, g, max_wait_s=10.0,
+                           mode="compiled", spmm_impl="segment",
+                           pipeline_depth=2)
+    b0 = _requests(stream[0])
+    for r in b0:
+        eng.submit_request(r)
+    assert eng.poll(now=100.0) == []     # aged: batch 0 dispatched
+    eng._inflight[0].delivery.result(timeout=60)
+    assert eng.poll(now=100.0) == b0     # empty queue: opportunistic
+    rec = eng.batch_timings[-1]
+    assert rec["early"] == 1 and rec["lead_s"] > 0.0
+    assert not eng._inflight
+    eng.close()
+
+
+def test_pipelined_matches_serial_under_thread_switching(setup):
+    """Depth 2 (with the completion waiter) against depth 1 on one long
+    stream, the interpreter switching threads every microsecond:
+    bit-identical answers, the same batch grouping and FIFO order;
+    serial, no batch is ever delivered ahead of its finalize."""
+    g, cfg, params, nai = setup
+    rng = np.random.default_rng(11)
+    stream = [rng.choice(g.test_idx, size=s, replace=False)
+              for s in rng.integers(20, 33, size=12)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        out = {}
+        for depth in (1, 2):
+            eng = NAIServingEngine(cfg, nai, params, g, max_wait_s=10.0,
+                                   mode="compiled", spmm_impl="segment",
+                                   pipeline_depth=depth)
+            done = []
+            for nodes in stream:
+                eng.submit(nodes)
+                done += eng.step()
+            done += eng.flush()
+            out[depth] = (done, list(eng.batch_timings))
+            assert (eng._waiter is None) == (depth == 1)
+            eng.close()
+    finally:
+        sys.setswitchinterval(interval)
+    (serial, rs), (piped, rp) = out[1], out[2]
+    for key in ("node_id", "prediction", "exit_order", "batch_id"):
+        assert ([getattr(r, key) for r in piped]
+                == [getattr(r, key) for r in serial]), key
+    assert [r.batch_id for r in piped] == sorted(r.batch_id for r in piped)
+    assert all(r.status == "completed" for r in piped)
+    assert [r["batch"] for r in rp] == [r["batch"] for r in rs]
+    assert all(r["early"] == 0 and r["lead_s"] == 0.0 for r in rs)
+    assert all(r["early"] in (0, 1) and r["lead_s"] >= 0.0 for r in rp)
+    assert all(r["lead_s"] == 0.0 for r in rp if not r["early"])
+
+
+def test_on_done_hands_answers_over_on_the_waiter(setup, stream,
+                                                  monkeypatch):
+    """The delivery contract when pipelined: each request's `on_done`
+    runs once, on the completion waiter, at `done_s` — while batch 1's
+    host stage still holds the engine thread, before `step()` returns
+    the request."""
+    g, cfg, params, nai = setup
+    eng = NAIServingEngine(cfg, nai, params, g, max_wait_s=10.0,
+                           mode="compiled", spmm_impl="segment",
+                           pipeline_depth=2)
+    calls, seen = [], {}
+
+    def on_done(r):
+        calls.append((r, threading.current_thread().name, r.status,
+                      r.done_s, time.perf_counter()))
+
+    def slow_sample(*args, **kwargs):
+        if eng._inflight:               # batch 1's host stage
+            eng._inflight[0].delivery.result(timeout=60)
+            seen["calls"] = list(calls)
+        return sample_support(*args, **kwargs)
+
+    monkeypatch.setattr(engine_mod, "sample_support", slow_sample)
+    b0 = [Request(int(n), 0.0, on_done=on_done) for n in stream[0]]
+    for r in b0:
+        eng.submit_request(r)
+    assert eng.step() == []
+    eng.submit(stream[1])
+    assert eng.step() == b0
+    assert [c[0] for c in seen["calls"]] == b0   # all before step returned
+    assert calls == seen["calls"]                # and only once each
+    for r, thread, status, done_s, t in calls:
+        assert thread.startswith("nai-waiter")
+        assert status == "completed" and done_s == r.done_s <= t
+    eng.close()
+
+
+@pytest.mark.parametrize("mode", ["host", "compiled"])
+def test_on_done_runs_on_the_caller_when_serial(setup, stream, mode):
+    """Serial (depth 1, either mode), the delivery happens inside the
+    finalize on the thread that calls `step()`: each `on_done` runs once,
+    after `done_s` and before the batch is counted."""
+    g, cfg, params, nai = setup
+    eng = NAIServingEngine(cfg, nai, params, g, max_wait_s=10.0,
+                           mode=mode, spmm_impl="segment",
+                           pipeline_depth=1)
+    calls = []
+
+    def on_done(r):
+        calls.append((r, threading.current_thread(), r.status,
+                      r.batch_id, r.done_s))
+
+    b0 = [Request(int(n), 0.0, on_done=on_done) for n in stream[0]]
+    for r in b0:
+        eng.submit_request(r)
+    assert eng.step() == b0
+    assert [c[0] for c in calls] == b0
+    for r, thread, status, bid, done_s in calls:
+        assert thread is threading.current_thread()
+        assert (status, bid, done_s) == ("completed", -1, r.done_s)
+        assert r.batch_id == 0
+    assert eng._waiter is None
+    eng.close()
+
+
+def test_watchdog_polls_back_off(setup, monkeypatch):
+    """Armed, the watchdog's readiness polls start at 100 us and double up
+    to `_WATCH_POLL_MAX_S`, so a long device stage wakes the waiter a few
+    hundred times a second at most."""
+    g, cfg, params, nai = setup
+    eng = NAIServingEngine(cfg, nai, params, g, max_wait_s=10.0,
+                           mode="compiled", spmm_impl="segment",
+                           pipeline_depth=2, watchdog_s=60.0)
+
+    class ReadyAfter:
+        def __init__(self, n):
+            self.n = n
+
+        def is_ready(self):
+            self.n -= 1
+            return self.n < 0
+
+    pauses = []
+    monkeypatch.setattr(engine_mod.time, "sleep", pauses.append)
+    fl = engine_mod._Inflight([], np.zeros(0, np.int64), 0, ReadyAfter(10),
+                              ReadyAfter(0), {},
+                              t_submit=time.perf_counter())
+    eng._watchdog_sync(fl)
+    cap = engine_mod._WATCH_POLL_MAX_S
+    assert pauses == pytest.approx(
+        [min(1e-4 * 2 ** i, cap) for i in range(10)])
+    assert pauses[-1] == cap
+
+
+def test_armed_watchdog_waiter_matches_unarmed(setup, stream):
+    """With the watchdog armed the waiter waits by polling instead of
+    blocking: same answers and batches as unarmed, still delivered ahead
+    of the finalize."""
+    g, cfg, params, nai = setup
+    out = {}
+    for wd in (None, 60.0):
+        eng = NAIServingEngine(cfg, nai, params, g, max_wait_s=10.0,
+                               mode="compiled", spmm_impl="segment",
+                               pipeline_depth=2, watchdog_s=wd)
+        done = []
+        for nodes in stream:
+            eng.submit(nodes)
+            done += eng.step()
+            eng._inflight[-1].delivery.result(timeout=60)
+        done += eng.flush()
+        assert all(r["early"] == 1 for r in eng.batch_timings)
+        out[wd] = [(r.node_id, r.prediction, r.exit_order, r.batch_id)
+                   for r in done]
+        eng.close()
+    assert out[60.0] == out[None]
+
+
+def test_close_stops_the_waiter(setup, stream):
+    g, cfg, params, nai = setup
+    eng = NAIServingEngine(cfg, nai, params, g, max_wait_s=10.0,
+                           mode="compiled", spmm_impl="segment",
+                           pipeline_depth=2)
+    assert eng._waiter is None           # started by the first batch
+    eng.submit(stream[0])
+    eng.step()
+    threads = list(eng._waiter._threads)
+    assert len(threads) == 1 and threads[0].is_alive()
+    eng.close()
+    assert eng._waiter is None and not eng._inflight
+    for t in threads:
+        t.join(timeout=10)
+        assert not t.is_alive()
+    eng.close()                          # idempotent
 
 
 def test_donation_gating(setup):

@@ -8,6 +8,7 @@ wiring all of it up with an EMPTY plan stays bit-identical to the
 pre-fault engine."""
 import dataclasses
 import os
+import threading
 
 import jax
 import numpy as np
@@ -19,7 +20,9 @@ from repro.gnn.store import (MmapStore, StoreCorruption, StoreIOError,
                              save_graph_store)
 from repro.serving import (BreakerConfig, CircuitBreaker, EngineConfig,
                            FaultPlan, FaultSpec, FaultyStore,
-                           NAIServingEngine, ServingFrontend, SLOClass)
+                           NAIServingEngine, Request, ServingFrontend,
+                           SLOClass)
+from repro.serving.faults import NaNGuardError
 
 IMPL = "segment"     # CPU-cheap reference backend for fault tests
 
@@ -126,6 +129,111 @@ def test_nan_guard_never_completes_poisoned_batch(setup):
         if r.status == "completed":
             assert 0 <= r.prediction < setup[1].num_classes
             assert 1 <= r.exit_order <= setup[3].t_max
+
+
+def test_guard_tripped_batch_gets_nothing_delivered(setup):
+    """Pipelined, the completion waiter hands out no answer of a batch
+    whose guards trip: its requests stay untouched until the engine
+    thread fails them, and the batches around it complete."""
+    eng = _engine(setup, faults=FaultPlan([FaultSpec("nan", at=(1,))]))
+    nids = _nodes(setup, n=24)
+    batches = [[Request(int(n), 0.0) for n in nids[i:i + 8]]
+               for i in (0, 8, 16)]
+    done = []
+    for b in batches[:2]:
+        for r in b:
+            eng.submit_request(r)
+        done += eng.step()
+    assert done == batches[0]            # batch 1 is the one in flight
+    with pytest.raises(NaNGuardError):
+        eng._inflight[0].delivery.result(timeout=60)
+    for r in batches[1]:
+        assert (r.status, r.prediction, r.exit_order, r.done_s) == \
+            ("pending", -1, -1, -1.0)
+    for r in batches[2]:
+        eng.submit_request(r)
+    done += eng.step()
+    done += eng.flush()
+    assert done == batches[0] + batches[1] + batches[2]
+    assert all(r.status == "failed" and "NaNGuardError" in r.error
+               and r.prediction == -1 for r in batches[1])
+    assert all(r.status == "completed"
+               for r in batches[0] + batches[2])
+    assert eng.stats.failed == 8 and eng.stats.served == 16
+    assert len(eng.batch_timings) == 2   # a failed batch publishes no record
+
+
+def test_failed_batch_reaches_on_done_at_finalize(setup):
+    """A guard-tripped batch's requests get their `on_done` once, as
+    failed, on the engine thread when it fails them — never from the
+    waiter; the batches around it are handed over as completed."""
+    eng = _engine(setup, faults=FaultPlan([FaultSpec("nan", at=(1,))]))
+    calls = []
+
+    def on_done(r):
+        calls.append((r, r.status, threading.current_thread().name))
+
+    nids = _nodes(setup, n=24)
+    batches = [[Request(int(n), 0.0, on_done=on_done)
+                for n in nids[i:i + 8]] for i in (0, 8, 16)]
+    done = []
+    for b in batches:
+        for r in b:
+            eng.submit_request(r)
+        done += eng.step()
+    done += eng.flush()
+    assert done == batches[0] + batches[1] + batches[2]
+    assert sorted(id(c[0]) for c in calls) == sorted(id(r) for r in done)
+    main = threading.current_thread().name
+    for r, status, thread in calls:
+        if r in batches[1]:
+            assert (status, thread) == ("failed", main)
+        else:
+            assert status == "completed" and thread.startswith("nai-waiter")
+
+
+def test_raising_on_done_is_kept_on_its_request(setup):
+    """A client callback that raises does not fail the batch: its error is
+    kept on its own request, and the other callbacks still run."""
+    eng = _engine(setup)
+    called = []
+
+    def on_done(r):
+        called.append(r)
+        if len(called) == 3:
+            raise RuntimeError("client bug")
+
+    reqs = [Request(int(n), 0.0, on_done=on_done)
+            for n in _nodes(setup, n=8)]
+    for r in reqs:
+        eng.submit_request(r)
+    done = eng.step() + eng.flush()
+    assert done == reqs and called == reqs
+    assert all(r.status == "completed" for r in reqs)
+    assert reqs[2].error == "on_done: RuntimeError: client bug"
+    assert all(r.error == "" for r in reqs[:2] + reqs[3:])
+    assert eng.stats.served == 8 and eng.stats.failed == 0
+
+
+def test_failing_cache_fill_still_returns_the_batch(setup, monkeypatch):
+    """The cache fill runs after the answers are out: one that raises
+    skips the insert and is counted, and the batch is still counted,
+    recorded and returned (every accepted request ends exactly once)."""
+    eng = _engine(setup, cache_nodes=4096)
+
+    def broken_fill(*args, **kwargs):
+        raise MemoryError("cache fill")
+
+    monkeypatch.setattr(eng.cache, "fill", broken_fill)
+    nids = _nodes(setup, n=16)
+    done = _serve(eng, nids)
+    assert [r.node_id for r in done] == [int(n) for n in nids]
+    assert all(r.status == "completed" for r in done)
+    assert [r.batch_id for r in done] == [0] * 8 + [1] * 8
+    assert eng.stats.fill_errors == 2
+    assert eng.stats.served == 16 and eng.stats.failed == 0
+    assert len(eng.batch_timings) == 2
+    assert len(eng.cache) == 0
 
 
 def test_poll_finalizes_host_materialized_results(setup):

@@ -4,6 +4,7 @@ per-class engines, bounded-lane backpressure, the fixed batch former
 bit-parity invariants — front-end == direct engine serving, pipelined ==
 serial — on a deterministic virtual-clock request stream."""
 import dataclasses
+import time
 from collections import defaultdict
 
 import jax
@@ -249,6 +250,32 @@ def test_goodput_accounting(setup):
     s = fe.summary()["gold"]
     assert s["goodput_frac"] == pytest.approx(0.5)
     assert s["batches"] >= 1
+
+
+def test_submit_on_done_is_called_at_the_scored_instant(setup):
+    """`submit(on_done=...)` reaches the engine's delivery: each accepted
+    request's callback runs once with it terminal, and its deadline is
+    scored against the `done_s` stamped just before that call; a shed
+    request gets no call."""
+    g, cfg, params, nai = setup
+    fe = ServingFrontend(cfg, params, g, _two_classes(nai, queue_depth=4),
+                         mode="compiled", pipeline_depth=2,
+                         spmm_impl="segment")
+    calls = []
+
+    def on_done(r):
+        calls.append((r, r.status, r.done_s, time.perf_counter()))
+
+    reqs = [fe.submit(int(n), "gold", budget_s=1e6, on_done=on_done)
+            for n in g.test_idx[:5]]
+    assert reqs[4] is None                         # shed: lane is full
+    fe.flush()
+    assert [c[0] for c in calls] == reqs[:4]
+    for r, status, done_s, t in calls:
+        assert status == "completed" and done_s == r.done_s <= t
+        assert r.on_done is on_done and r.within_deadline
+    assert fe.stats["gold"].deadline_hits == 4
+    fe.close()
 
 
 def test_pending_and_reset(setup):

@@ -147,10 +147,11 @@ def test_queue_wait_and_stage_intervals_on_a_virtual_clock(setup,
     eng.submit(first[:5], now=90.0)
     eng.submit(first[5:], now=95.5)
     assert eng.step() == []            # batch 0 in flight
+    eng._inflight[0].delivery.result(timeout=60)   # the waiter delivered it
     clock.t = 102.0
     eng.submit(second, now=101.0)
-    done = eng.step()                  # batch 1's stages, then batch 0 syncs
-    done += eng.flush()
+    done = eng.step()                  # batch 1's stages, then batch 0's
+    done += eng.flush()                # finalize joins the waiter
     r0, r1 = eng.batch_timings
     host = 0.25 + 0.0625 + 0.03125 + 0.015625   # faults hook + host stage
     for rec in (r0, r1):
@@ -166,8 +167,14 @@ def test_queue_wait_and_stage_intervals_on_a_virtual_clock(setup,
     assert all(r.batched_s == 100.0 for r in b0)
     assert r0["queue_wait_s"] == sum(r.batched_s - r.arrival_s for r in b0)
     assert r0["queue_wait_s"] == 5 * 10.0 + 7 * 4.5
-    # batch 0 is held from its dispatch until batch 1 has been dispatched
-    assert r0["hold_s"] == (102.0 + host + 0.125) - (100.0 + host + 0.125)
+    # batch 0's hold ends when its device results are ready, before the
+    # clock moved: its answers left at its dispatch's end, 2 s before
+    # the engine thread reached its finalize after batch 1's dispatch
+    dispatched0 = 100.0 + host + 0.125
+    assert r0["hold_s"] == 0.0
+    assert all(r.done_s == dispatched0 for r in b0)
+    assert r0["early"] == 1
+    assert r0["lead_s"] == (102.0 + host + 0.125) - dispatched0
     for r in b0:   # the record accounts for the whole latency
         parts = (r.batched_s - r.arrival_s + r0["host_s"] + r0["dispatch_s"]
                  + r0["hold_s"] + r0["sync_s"])
