@@ -1,12 +1,15 @@
 """Whole-graph offline jobs through `run_full_graph_infer`, back to back.
 
-The traffic file names only this driver; the jobs run on one device. The
-backend is the configuration's ``spmm_impl``, and the program checkpoints
-after every superstep. Each job is cold: a fresh checkpoint directory inside the checkout,
-no resume, deleted after the job. Jobs run until one ends past
-`seconds`; the window is the wall time of those whole jobs, packing,
-checkpoint writes and classification included. Set-up runs one job, so
-the superstep and classifier programs are in the compilation cache.
+The traffic file names only this driver. The jobs run on the cell's
+chips: on one, with no mesh; on several, over the program's serving mesh
+of that many devices, exchanging rows by the configuration's
+``engine.gather_mode``. The backend is the configuration's ``spmm_impl``,
+and the program checkpoints after every superstep. Each job is cold: a
+fresh checkpoint directory inside the checkout, no resume, deleted after
+the job. Jobs run until one ends past `seconds`; the window is the wall
+time of those whole jobs, packing, checkpoint writes and classification
+included. Set-up runs one job, so the superstep and classifier programs
+are in the compilation cache.
 """
 from __future__ import annotations
 
@@ -22,13 +25,13 @@ from yardstick.measure import Profiler, memory_peak_bytes, span
 from yardstick.peaks import peaks
 
 
-def _job(dep, ocfg, trace):
+def _job(dep, ocfg, mesh, trace):
     from repro.launch.full_graph_infer import run_full_graph_infer
     shutil.rmtree(ocfg.ckpt_dir, ignore_errors=True)
     try:
         with span("bench.offline_job", trace):
             return run_full_graph_infer(dep.store, dep.gnn, dep.params,
-                                        dep.nai, ocfg)
+                                        dep.nai, ocfg, mesh=mesh)
     finally:
         shutil.rmtree(ocfg.ckpt_dir, ignore_errors=True)
 
@@ -38,10 +41,18 @@ def run(ctx):
     log = ctx.log
     counter = compiles.counter()
     dep = build(ctx.cell.config, ctx.seed)
+    engine = dep.config["engine"]
+    chips = ctx.cell.chips
+    mesh = None
+    sharded = {}
+    if chips > 1:
+        from repro.launch.mesh import make_serving_mesh
+        mesh = make_serving_mesh(chips)
+        sharded = {"gather_mode": engine["gather_mode"]}
     ocfg = OfflineConfig(ckpt_dir=str(ctx.out_dir / "ckpt"),
-                         spmm_impl=dep.config["engine"]["spmm_impl"],
-                         resume=False)
-    _job(dep, ocfg, False)
+                         spmm_impl=engine["spmm_impl"], resume=False,
+                         **sharded)
+    _job(dep, ocfg, mesh, False)
     prof = Profiler(ctx.out_dir, ctx.trace)
     jobs = []
     in_window = {}
@@ -52,7 +63,7 @@ def run(ctx):
         start = time.perf_counter()
         with span("bench.window", ctx.trace):
             while True:
-                jobs.append(_job(dep, ocfg, ctx.trace))
+                jobs.append(_job(dep, ocfg, mesh, ctx.trace))
                 if time.perf_counter() - start >= ctx.seconds:
                     break
         wall = time.perf_counter() - start
@@ -66,7 +77,7 @@ def run(ctx):
     log(f"  checkpoint bytes per job: {stats[-1]['ckpt_bytes']}")
     log(f"compiles in window: {in_window}")
     record = {
-        "setup_s": setup_s, "seconds": float(ctx.seconds),
+        "setup_s": setup_s, "seconds": float(ctx.seconds), "chips": chips,
         "wall_s": wall, "jobs": len(jobs), "nodes": n,
         "pack_s": [s["pack_s"] for s in stats],
         "ckpt_s": [s["ckpt_s"] for s in stats],

@@ -180,7 +180,8 @@ def run(ctx):
 
     record = {
         "setup_s": setup_s, "seconds": float(ctx.seconds),
-        "latency_s": latency, "deadline_hits": deadline_hits,
+        "chips": ctx.cell.chips, "latency_s": latency,
+        "deadline_hits": deadline_hits,
         "offered": len(times), "served": st.served, "batches": st.batches,
         "batch_size": dep.nai.batch_size,
         "host_s": [t["host_s"] for t in timings], "h2d_bytes": h2d,

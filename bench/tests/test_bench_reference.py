@@ -1,10 +1,13 @@
 """The benchmark's own generator, arrivals and reference, against the
-program's host Algorithm 1 at a tiny size."""
+program's host Algorithm 1 at a tiny size; and the one reference of a
+deployment, its products in threaded row blocks, against the two it
+replaced."""
 import numpy as np
 import pytest
+import scipy.sparse as sp
 
 from yardstick import arrivals, sbm
-from yardstick.reference import Reference, gaps
+from yardstick.reference import Reference, gaps, product
 
 SIZE = dict(nodes=600, edges=2400, features=16, classes=4)
 
@@ -106,3 +109,95 @@ def test_gaps_measure_how_far_an_answer_is_wrong(graph):
     bad = a.orders.copy()
     bad[0] = 0
     assert gaps(a, ref.t_s, 1, 3, bad, a.preds) == (float("inf"),) * 2
+
+
+@pytest.mark.parametrize("blocks", [1, 3, 8])
+def test_threaded_product_is_bit_equal_to_one_product(graph, blocks):
+    ref = Reference(graph, {}, r=0.5, t_min=1, t_max=1)
+    x = np.asarray(graph.features, np.float64)
+    assert np.array_equal(product(ref.A, x, blocks=blocks), ref.A @ x)
+    x32 = graph.features
+    assert np.array_equal(product(ref.A.astype(np.float32), x32,
+                                  blocks=blocks),
+                          ref.A.astype(np.float32) @ x32)
+
+
+class _TwoReferences:
+    """The float64 reference as it was, built twice per deployment: once
+    at t_max 1 for T_s, once with the heads for the check; every product
+    one SciPy call, the series built whole at construction."""
+
+    def __init__(self, g, weights, *, r, t_min, t_max, t_s=None):
+        self.g, self.r, self.t_s = g, r, t_s
+        self.t_min, self.t_max = t_min, t_max
+        loop = g.src == g.dst
+        self.dt = np.bincount(g.dst[~loop], minlength=g.n) + 1.0
+        coef = self.dt[g.dst] ** (r - 1.0) * self.dt[g.src] ** (-r)
+        self.src, self.dst = g.src.astype(np.int64), g.dst.astype(np.int64)
+        self.nonloop = ~loop
+        A = sp.csr_matrix((coef, (self.dst, self.src)), shape=(g.n, g.n))
+        self.weights = {l: (np.asarray(w, np.float64),
+                            np.asarray(b, np.float64))
+                        for l, (w, b) in weights.items()}
+        x = np.asarray(g.features, np.float64)
+        self.series = [x]
+        for _ in range(t_max):
+            x = A @ x
+            self.series.append(x)
+
+    def stationary(self, mask, rows):
+        inside = mask[self.src] & mask[self.dst] & self.nonloop
+        denom = 2.0 * (int(inside.sum()) // 2) + int(mask.sum())
+        s = np.where(mask, self.dt ** (1.0 - self.r), 0.0) @ self.series[0]
+        return (self.dt[rows] ** self.r / denom)[:, None] * s[None, :]
+
+    def first_step_median(self):
+        x_inf = self.stationary(np.ones(self.g.n, bool), np.arange(self.g.n))
+        return float(np.median(np.linalg.norm(self.series[1] - x_inf, axis=1)))
+
+    def answers(self, rows, mask):
+        x_inf = self.stationary(mask, rows)
+        k, C = len(rows), self.g.num_classes
+        dist = np.full((self.t_max + 1, k), np.nan)
+        logits = np.zeros((self.t_max + 1, k, C))
+        for l in range(1, self.t_max + 1):
+            x = self.series[l][rows]
+            w, b = self.weights[l]
+            dist[l] = np.linalg.norm(x - x_inf, axis=1)
+            logits[l] = x @ w + b
+        orders = np.full(k, self.t_max, np.int64)
+        open_ = np.ones(k, bool)
+        for l in range(self.t_min, self.t_max):
+            now = open_ & (dist[l] < self.t_s)
+            orders[now] = l
+            open_ &= ~now
+        preds = logits[orders, np.arange(k)].argmax(axis=1)
+        return orders, preds, dist, logits
+
+
+@pytest.mark.parametrize("size", [SIZE, dict(nodes=70000, edges=300000,
+                                              features=8, classes=5)])
+def test_one_reference_reads_as_the_two_it_replaced(size):
+    g = sbm.generate(**size, seed=21)
+    rng = np.random.default_rng(3)
+    f, c = size["features"], size["classes"]
+    weights = {l: (rng.standard_normal((f, c)), rng.standard_normal(c))
+               for l in (1, 2, 3)}
+    t_s = _TwoReferences(g, {}, r=0.5, t_min=1, t_max=1).first_step_median()
+    old = _TwoReferences(g, weights, r=0.5, t_min=1, t_max=3, t_s=t_s)
+    base = Reference(g, {}, r=0.5, t_min=1, t_max=3)
+    base.t_s = base.first_step_median()
+    assert base.t_s == t_s
+    new = base.with_weights(weights)
+    assert new.series(1) is base.series(1)
+    everyone = np.ones(g.n, bool)
+    for rows, mask in [(np.arange(g.n), everyone),
+                       (np.sort(g.test_idx[:64]), None)]:
+        if mask is None:
+            mask = new.support_mask(rows)
+        a = new.answers(rows, mask)
+        orders, preds, dist, logits = old.answers(rows, mask)
+        assert np.array_equal(a.orders, orders)
+        assert np.array_equal(a.preds, preds)
+        assert np.array_equal(a.dist, dist, equal_nan=True)
+        assert np.array_equal(a.logits, logits)

@@ -56,6 +56,28 @@ def make_root(tmp: Path) -> Path:
     return tmp
 
 
+def add_sharded_offline(root: Path, chips: int) -> str:
+    """A cell of whole-graph jobs on `chips` devices of the tiny graph,
+    whose configuration exchanges rows by all-to-all; its name."""
+    cfg = dict(TINY_CONFIG, name="tiny-sgc-a2a",
+               engine=dict(TINY_CONFIG["engine"], gather_mode="alltoall"))
+    (root / "bench" / "configs" / "tiny-sgc-a2a.json").write_text(
+        json.dumps(cfg))
+    name = f"tiny.offline-d{chips}"
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "tiny-sgc-a2a", "source": cfg["source"],
+                            "file": "bench/configs/tiny-sgc-a2a.json",
+                            "reduced": cfg["reduced"], "why": "tests"})
+    spec["workloads"].append({"name": name, "config": "tiny-sgc-a2a",
+                              "traffic": "tiny-offline", "chips": chips,
+                              "why": "tests"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "tiny.offline" in m.get("workloads", []):
+            m["workloads"].append(name)
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return name
+
+
 def any_device(chips):
     import jax
     return jax.devices()

@@ -52,26 +52,27 @@ def compare(ref: Reference, batches: List[Batch], *,
     everyone = np.ones(g.n, bool) if whole_graph else None
     cache = {}
     for b in batches:
-        uniq = np.unique(b.nodes)
+        uniq, first = np.unique(b.nodes, return_index=True)
         key = uniq.tobytes() if whole_graph else None
         if key in cache:
-            mask, a, c = cache[key]
+            a, c, size = cache[key]
         else:
             mask = everyone if whole_graph else ref.support_mask(uniq)
             a = ref.answers(uniq, mask)
             c = control.answers(uniq, mask) if control is not None else None
+            size = ref.support_size(mask)
             if whole_graph:
-                cache[key] = (mask, a, c)
-        idx = np.searchsorted(uniq, b.nodes)
-        e, lg = gaps(_take(a, idx), ref.t_s, ref.t_min, ref.t_max,
-                     b.orders, b.preds)
+                cache[key] = (a, c, size)
+        # a job answers every node once, in node order: its rows are `a`'s
+        same = len(uniq) == len(b.nodes) and np.array_equal(uniq, b.nodes)
+        e, lg = gaps(a if same else _take(a, np.searchsorted(uniq, b.nodes)),
+                     ref.t_s, ref.t_min, ref.t_max, b.orders, b.preds)
         exit_gap, logit_gap = max(exit_gap, e), max(logit_gap, lg)
         if c is not None:
             e, lg = gaps(a, ref.t_s, ref.t_min, ref.t_max, c.orders, c.preds)
             ctl_exit, ctl_logit = max(ctl_exit, e), max(ctl_logit, lg)
         compared += len(b.nodes)
-        rows, edges = ref.support_size(mask)
-        first = np.unique(b.nodes, return_index=True)[1]
+        rows, edges = size
         work.append(nai_work(rows=rows, edges=edges,
                              width=g.features.shape[1], steps=ref.t_max,
                              orders=b.orders[first], t_min=ref.t_min,

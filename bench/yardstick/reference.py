@@ -15,6 +15,9 @@ A node exits at the first order l in [t_min, t_max) whose Eq. 8 distance
 ||X^(l)_i - x_inf_i|| is below T_s, else at t_max, and is classified by
 the linear head of that order.
 
+Products and row norms run as blocks of rows on threads (`par`), each row
+computed as one call would, so the numbers do not depend on the blocks.
+
 ``precision="bfloat16"`` is the control: the same computation with every
 stored array rounded to bfloat16 (features, coefficients, each step's
 output, the stationary state, distances, weights and logits), products
@@ -22,6 +25,7 @@ accumulated in float32, as a bfloat16 serving path would compute it.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 from typing import Dict, Tuple
 
@@ -29,6 +33,7 @@ import ml_dtypes
 import numpy as np
 import scipy.sparse as sp
 
+from . import par
 from .sbm import SBMGraph
 
 
@@ -47,7 +52,39 @@ class Answers:
     logits: np.ndarray     # (t_max + 1, k, C) logits per order (row 0 unused)
 
 
+def product(A: sp.csr_matrix, x: np.ndarray, blocks: int = None) -> np.ndarray:
+    """`A @ x` as blocks of rows on threads. Each row sums its entries in
+    the same order as one product does, so the result is bit-identical at
+    any number of blocks."""
+    out = np.empty((A.shape[0],) + x.shape[1:],
+                   np.result_type(A.dtype, x.dtype))
+
+    def block(lo, hi):
+        s, e = A.indptr[lo], A.indptr[hi]
+        rows = sp.csr_matrix((A.data[s:e], A.indices[s:e],
+                              A.indptr[lo:hi + 1] - s),
+                             shape=(hi - lo, A.shape[1]))
+        out[lo:hi] = rows @ x
+    par.run(block, par.even(len(out), blocks, row_bytes=out[:1].nbytes))
+    return out
+
+
+def row_norms(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    """`np.linalg.norm(a - b, axis=1)` by blocks of rows on threads."""
+    out = np.empty(len(a), np.result_type(a.dtype, b.dtype))
+
+    def block(lo, hi):
+        out[lo:hi] = np.linalg.norm(a[lo:hi] - b[lo:hi], axis=1)
+    par.run(block, par.even(len(a), row_bytes=a[:1].nbytes))
+    return out
+
+
 class Reference:
+    """The reference over one graph. Everything that depends only on the
+    graph (the operator and the series X^(l)) is built once; the series
+    grows to an order when an order is first asked for. `with_weights`
+    shares it between heads."""
+
     def __init__(self, g: SBMGraph, weights: Dict[int, Tuple[np.ndarray, np.ndarray]],
                  *, r: float, t_min: int, t_max: int, t_s: float = None,
                  precision: str = "float64"):
@@ -61,30 +98,50 @@ class Reference:
         self.deg = np.bincount(g.dst[~loop], minlength=g.n).astype(np.float64)
         dt = self.deg + 1.0
         self.dt = dt
-        coef = dt[g.dst] ** (self.r - 1.0) * dt[g.src] ** (-self.r)
-        self.src, self.dst = g.src.astype(np.int64), g.dst.astype(np.int64)
+        # the per-edge dt**e of one power per node: the same numbers
+        coef = (dt ** (self.r - 1.0))[g.dst] * (dt ** (-self.r))[g.src]
+        self.src, self.dst = g.src, g.dst
         self.nonloop = ~loop
         dtype = np.float32 if self.low else np.float64
         self.A = sp.csr_matrix(
             ((bf16(coef) if self.low else coef).astype(dtype),
              (self.dst, self.src)), shape=(g.n, g.n))
-        self._pattern = sp.csr_matrix(
-            (np.ones(len(self.src), np.float32), (self.dst, self.src)),
-            shape=(g.n, g.n))
-        self.weights = {
-            l: ((bf16(w), bf16(b)) if self.low
-                else (np.asarray(w, np.float64), np.asarray(b, np.float64)))
-            for l, (w, b) in weights.items()}
+        self._pattern = None
+        self.weights = self._cast(weights)
         x = (bf16(g.features) if self.low
              else np.asarray(g.features, np.float64))
-        self.series = [x]
-        for _ in range(self.t_max):
-            x = self.A @ x
-            self.series.append(bf16(x) if self.low else x)
+        self._series = [x]
+
+    def _cast(self, weights):
+        if self.low:
+            return {l: (bf16(w), bf16(b)) for l, (w, b) in weights.items()}
+        return {l: (np.asarray(w, np.float64), np.asarray(b, np.float64))
+                for l, (w, b) in weights.items()}
+
+    def with_weights(self, weights: Dict[int, Tuple[np.ndarray, np.ndarray]]
+                     ) -> "Reference":
+        """This reference, its operator and series shared, with the heads
+        `weights`."""
+        out = copy.copy(self)
+        out.weights = self._cast(weights)
+        return out
+
+    def series(self, l: int) -> np.ndarray:
+        """X^(l) = Â^l X over every node (0 <= l <= t_max)."""
+        if not 0 <= l <= self.t_max:
+            raise ValueError(f"order {l} outside 0..{self.t_max}")
+        while len(self._series) <= l:
+            x = product(self.A, self._series[-1])
+            self._series.append(bf16(x) if self.low else x)
+        return self._series[l]
 
     # ----------------------------------------------------------- supports
     def support_mask(self, batch: np.ndarray) -> np.ndarray:
         """Nodes within t_max hops of `batch` (the sampled support)."""
+        if self._pattern is None:
+            self._pattern = sp.csr_matrix(
+                (np.ones(len(self.src), np.float32), (self.dst, self.src)),
+                shape=(self.g.n, self.g.n))
         mask = np.zeros(self.g.n, bool)
         mask[np.asarray(batch, np.int64)] = True
         for _ in range(self.t_max):
@@ -93,14 +150,17 @@ class Reference:
 
     def support_size(self, mask: np.ndarray) -> Tuple[int, int]:
         """(rows, directed edges with self loops) of the induced subgraph."""
+        if mask.all():
+            return len(mask), len(self.src)
         return int(mask.sum()), int((mask[self.src] & mask[self.dst]).sum())
 
     def stationary(self, mask: np.ndarray, rows: np.ndarray) -> np.ndarray:
         """Eq. 7 over the support `mask`, at `rows` (k, f)."""
-        inside = mask[self.src] & mask[self.dst] & self.nonloop
+        inside = (self.nonloop if mask.all()
+                  else mask[self.src] & mask[self.dst] & self.nonloop)
         denom = 2.0 * (int(inside.sum()) // 2) + int(mask.sum())
         w = np.where(mask, self.dt ** (1.0 - self.r), 0.0)
-        x0 = self.series[0]
+        x0 = self.series(0)
         if self.low:
             s = bf16(w.astype(np.float32) @ x0)
             c = bf16(self.dt[rows] ** self.r / denom)
@@ -116,18 +176,20 @@ class Reference:
         rows = np.asarray(rows, np.int64)
         x_inf = self.stationary(mask, rows)
         k, C = len(rows), self.g.num_classes
+        every = k == self.g.n and np.array_equal(rows, np.arange(k))
         dist = np.full((self.t_max + 1, k), np.nan)
         logits = np.zeros((self.t_max + 1, k, C))
         for l in range(1, self.t_max + 1):
-            x = self.series[l][rows]
+            x = self.series(l) if every else self.series(l)[rows]
             w, b = self.weights[l]
             if self.low:
                 diff = bf16(x - x_inf)
                 dist[l] = bf16(np.sqrt((diff * diff).sum(axis=1)))
                 logits[l] = bf16(x @ w + b)
             else:
-                dist[l] = np.linalg.norm(x - x_inf, axis=1)
-                logits[l] = x @ w + b
+                dist[l] = row_norms(x, x_inf)
+                np.matmul(x, w, out=logits[l])
+                logits[l] += b
         orders = np.full(k, self.t_max, np.int64)
         open_ = np.ones(k, bool)
         for l in range(self.t_min, self.t_max):
@@ -142,8 +204,7 @@ class Reference:
         over the whole graph, in float64."""
         everyone = np.ones(self.g.n, bool)
         x_inf = self.stationary(everyone, np.arange(self.g.n))
-        return float(np.median(np.linalg.norm(self.series[1] - x_inf,
-                                              axis=1)))
+        return float(np.median(row_norms(self.series(1), x_inf)))
 
 
 def gaps(ref: Answers, t_s: float, t_min: int, t_max: int,

@@ -1,11 +1,16 @@
 """Reduction of a JAX profiler trace (`.xplane.pb`) to device metrics.
 
+* Every time is per device: summed over the device planes that ran
+  anything and divided by their number, so on four chips a program's
+  time per execution is one chip's, not four chips' together.
 * Device busy time is the union of the intervals of the operations on a
-  device plane's ``XLA Ops`` line, clipped to the measured window and
-  averaged over the device planes that ran anything.
+  device plane's ``XLA Ops`` line, clipped to the measured window.
 * Device time is attributed to programs by the ``XLA Modules`` line,
   whose events are named ``<module>(<fingerprint>)``: a jitted Python
   function ``run`` is the module ``jit_run``.
+* Collective time is the union of the collective ops (all-to-all,
+  all-gather, collective-permute, all-reduce, reduce-scatter, by op
+  name); its exposed part is what no other op on that device overlaps.
 * Each idle gap of a device inside the window is attributed to the
   benchmark's own host span (a `jax.profiler.TraceAnnotation` whose name
   starts with ``bench.``) that overlaps it most: what the host was doing
@@ -20,12 +25,15 @@ import bisect
 import collections
 import glob
 import os
+import re
 from typing import Dict, List, Optional, Tuple
 
 Interval = Tuple[float, float]
 
 WINDOW_SPAN = "bench.window"
 SPAN_PREFIX = "bench."
+COLLECTIVE = re.compile(r"all[-_]to[-_]all|all[-_]gather|collective[-_]permute"
+                        r"|all[-_]reduce|reduce[-_]scatter")
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -125,12 +133,55 @@ def read_events(path: str):
 
 def reduce_trace(path: str, *, top: int = 10,
                  window: Optional[Interval] = None) -> Dict:
-    """Busy and window seconds, per-module device time, the device
-    operations that took most time and the longest idle gaps, each gap
-    named by the host span it falls in."""
+    """`reduce_events` of the trace at `path`."""
     devices, spans = read_events(path)
     if not devices:
         raise ValueError(f"{path}: no device plane ran an operation")
+    return reduce_events(devices, spans, top=top, window=window)
+
+
+def is_collective(op: str) -> bool:
+    """An exchange between devices, by its XLA op name (``all-to-all.3``,
+    ``all-gather-start``, ``collective-permute-done.1``, ...)."""
+    return COLLECTIVE.search(op) is not None
+
+
+def _length(intervals: List[Interval]) -> float:
+    return sum(b - a for a, b in intervals)
+
+
+def uncovered(cover: List[Interval], other: List[Interval]) -> float:
+    """Length of the merged `cover` that the merged `other` leaves open,
+    in one pass over both."""
+    total, j = 0.0, 0
+    for a, b in cover:
+        while j < len(other) and other[j][1] <= a:
+            j += 1
+        t, k = a, j
+        while k < len(other) and other[k][0] < b:
+            if other[k][0] > t:
+                total += other[k][0] - t
+            t = max(t, other[k][1])
+            k += 1
+        if b > t:
+            total += b - t
+    return total
+
+
+def reduce_events(devices: List[Dict], spans: List[Tuple[float, float, str]],
+                  *, top: int = 10, window: Optional[Interval] = None
+                  ) -> Dict:
+    """Busy and window seconds, per-module device time, time in
+    collectives, the device operations that took most time and the longest
+    idle gaps, each gap named by the host span it falls in. `devices` and
+    `spans` are as `read_events` gives them.
+
+    Every time is per device, the mean over the device planes that ran
+    anything: a module's ``count`` is its executions on one device and its
+    ``total_s`` their seconds there, so ``total_s / count`` is one
+    execution's time on one device. ``collective_s`` is the time covered
+    by collective ops, ``exposed_collective_s`` the part of it in which no
+    other op runs on that device."""
     if window is None:
         marks = [s for s in spans if s[2] == WINDOW_SPAN]
         if marks:
@@ -141,13 +192,19 @@ def reduce_trace(path: str, *, top: int = 10,
     lo, hi = window
     host = sorted(s for s in spans if s[2] != WINDOW_SPAN)
     host_starts = [s[0] for s in host]
-    busy_total = 0.0
+    busy_total = coll_total = exposed_total = 0.0
     modules: Dict[str, Dict[str, float]] = {}
     op_time: Dict[str, float] = collections.Counter()
     idle: List[Tuple[str, float]] = []
     for dev in devices:
         busy = union(clip([(a, b) for a, b, _ in dev["ops"]], lo, hi))
-        busy_total += sum(b - a for a, b in busy)
+        busy_total += _length(busy)
+        split = {True: [], False: []}
+        for a, b, name in dev["ops"]:
+            split[is_collective(name)].append((a, b))
+        coll = union(clip(split[True], lo, hi))
+        coll_total += _length(coll)
+        exposed_total += uncovered(coll, union(clip(split[False], lo, hi)))
         mods = sorted(dev["modules"])
         for a, b, name in mods:
             if not lo <= (a + b) / 2 < hi:
@@ -171,9 +228,12 @@ def reduce_trace(path: str, *, top: int = 10,
     return {
         "window_s": (hi - lo) * 1e-9,
         "busy_s": busy_total * 1e-9 / n_dev,
+        "collective_s": coll_total * 1e-9 / n_dev,
+        "exposed_collective_s": exposed_total * 1e-9 / n_dev,
         "devices": n_dev,
-        "modules": {k: {"count": v["count"], "total_s": v["total_s"] / n_dev}
+        "modules": {k: {"count": v["count"] / n_dev,
+                        "total_s": v["total_s"] / n_dev}
                     for k, v in modules.items()},
-        "device_ops": [[k, v] for k, v in op_time.most_common(top)],
+        "device_ops": [[k, v / n_dev] for k, v in op_time.most_common(top)],
         "idle_gaps": [[k, v] for k, v in idle[:top]],
     }
